@@ -43,12 +43,10 @@ def _box_is_contended() -> float | None:
 
 def _default_backend() -> str:
     """The JAX backend rows are stamped with (lazy import — keep the module
-    importable without initializing a device)."""
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
+    importable without initializing a device).  A backend that fails to
+    start raises: a row is never stamped with a device it did not run on."""
+    import jax
+    return jax.default_backend()
 
 
 def bench_record(bench: str, *, scenario: str, V: int, solver: str,

@@ -63,4 +63,7 @@ def main() -> dict:
 
 
 if __name__ == "__main__":
+    from repro.runtime import use_compile_cache
+
+    use_compile_cache()
     main()

@@ -199,6 +199,9 @@ def main(argv=()):
 
 
 if __name__ == "__main__":
+    from repro.runtime import use_compile_cache
+
+    use_compile_cache()
     import sys
 
     main(sys.argv[1:])

@@ -268,6 +268,9 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.runtime import use_compile_cache
+
+    use_compile_cache()
     if "--smoke" in sys.argv[1:]:
         smoke()
     else:

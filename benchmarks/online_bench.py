@@ -345,4 +345,7 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.runtime import use_compile_cache
+
+    use_compile_cache()
     main()

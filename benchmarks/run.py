@@ -42,4 +42,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.runtime import use_compile_cache
+
+    use_compile_cache()
     main()

@@ -83,4 +83,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.runtime import use_compile_cache
+
+    use_compile_cache()
     main()

@@ -1,37 +1,23 @@
-"""Version-adaptive shims over JAX APIs that moved between releases.
+"""Thin spellings of the JAX mesh APIs the solver uses.
 
-The repo targets the public post-0.6 spellings (``jax.shard_map`` with
-``check_vma``, ``jax.make_mesh(..., axis_types=...)``); on older runtimes
-(e.g. 0.4.x, where shard_map lives in ``jax.experimental`` and takes
-``check_rep``, and ``make_mesh`` has no ``axis_types``) these helpers fall
-back to the equivalent legacy call so the same call sites run everywhere.
+``shard_map`` takes the solver's ``check`` flag as ``check_vma``, and
+``make_mesh`` builds meshes with Auto axis types (explicit sharding is
+opt-in per axis in current JAX).
 """
 
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):           # jax >= 0.6 spelling
 
-    def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-
-else:                                    # 0.4.x: experimental, check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check)
+def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the runtime supports
-    them (newer jax made Explicit sharding opt-in per axis; older versions
-    have no ``axis_types`` parameter and are Auto-only anyway)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)),
-        )
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with Auto axis types."""
+    axis_names = tuple(axis_names)
+    return jax.make_mesh(
+        tuple(axis_shapes), axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

@@ -552,8 +552,8 @@ def _anderson_mix(ax, af, ak, x_k, f_k, reg: float,
     m = ax.shape[0]
     valid = jnp.arange(m) >= (m - jnp.minimum(ak, m))            # (m,)
     dF = jnp.where(valid[:, None], f_k[None, :] - af, 0.0)       # (m, N)
-    gram = dF @ dF.T                                             # (m, m)
-    b = dF @ f_k                                                 # (m,)
+    gram = jnp.dot(dF, dF.T, precision=traffic_mod.HIGHEST)          # (m, m)
+    b = jnp.dot(dF, f_k, precision=traffic_mod.HIGHEST)              # (m,)
     if axis is not None:
         gram = jax.lax.psum(gram, axis)
         b = jax.lax.psum(b, axis)
@@ -562,7 +562,8 @@ def _anderson_mix(ax, af, ak, x_k, f_k, reg: float,
     gamma = jnp.where(valid, gamma, 0.0)
     g_k = x_k + f_k
     g_hist = ax + af                                             # (m, N)
-    return g_k - gamma @ (g_k[None, :] - g_hist)
+    return g_k - jnp.dot(gamma, g_k[None, :] - g_hist,
+                         precision=traffic_mod.HIGHEST)
 
 
 def _push_history(buf: jnp.ndarray, row: jnp.ndarray) -> jnp.ndarray:

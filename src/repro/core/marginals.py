@@ -30,8 +30,8 @@ import jax.numpy as jnp
 
 from repro.core.network import Instance
 from repro.core.traffic import (
-    Flows, Phi, comp_marginals, flows, link_marginals, resolve_solver,
-    stage_factors,
+    HIGHEST, Flows, Phi, comp_marginals, flows, link_marginals,
+    resolve_solver, stage_factors,
 )
 from repro.kernels import ops
 
@@ -77,8 +77,8 @@ def pdt_recursion(
     # with base_k = [link term] + phi_c_k * w_k * wnode * C' and the
     # nonnegativity clamp applied inside the fused sweep.
     link_term = jnp.einsum(
-        "akij,akij->aki", phi.e, inst.L[:, :, None, None] * Dp[None, None]
-    )  # (A, K1, V): sum_j phi_ij L_k D'_ij
+        "akij,akij->aki", phi.e, inst.L[:, :, None, None] * Dp[None, None],
+        precision=HIGHEST)  # (A, K1, V): sum_j phi_ij L_k D'_ij
     base = link_term + phi.c * (
         inst.w[:, :, None] * inst.wnode[None, None] * Cp[None, None])
     if solver == "sparse":
@@ -95,8 +95,8 @@ def _per_app_dense(inst, Dp, Cp, phi_e_a, phi_c_a, L_a, w_a):
     """Seed-path per-app recursion (dense per-stage solves) — the
     differential reference for solver="batched_lu"."""
     link_term = jnp.einsum(
-        "kij,kij->ki", phi_e_a, L_a[:, None, None] * Dp[None]
-    )
+        "kij,kij->ki", phi_e_a, L_a[:, None, None] * Dp[None],
+        precision=HIGHEST)
 
     def step(pdt_next, xs):
         phi_e_k, phi_c_k, lt_k, w_k = xs

@@ -34,6 +34,10 @@ from repro.core import costs
 from repro.core.network import Instance
 from repro.kernels import ops
 
+# Full float32 contractions on every backend: the TPU's default matmul
+# precision is one bfloat16 pass, which would change flows and costs.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 class Phi(NamedTuple):
     """Forwarding/offloading strategy (the optimization variable).
@@ -237,8 +241,8 @@ def flows(
     """
     t, g = stage_traffic(inst, phi, fact, solver=solver)
     f = t[..., None] * phi.e                                  # (A,K1,V,V)
-    F = jnp.einsum("ak,akij->ij", inst.L, f)
-    G = jnp.einsum("ak,aki->i", inst.w, g) * inst.wnode
+    F = jnp.einsum("ak,akij->ij", inst.L, f, precision=HIGHEST)
+    G = jnp.einsum("ak,aki->i", inst.w, g, precision=HIGHEST) * inst.wnode
     if axis is not None:
         F = jax.lax.psum(F, axis)
         G = jax.lax.psum(G, axis)

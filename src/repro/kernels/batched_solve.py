@@ -23,12 +23,14 @@ into ONE ``(B, V, V)`` device program:
     ``kernels.ops`` since interpret-mode Pallas cannot beat native LAPACK.
 
 Blocking scheme (§12): the (Vp, Vp) matrix is resident in VMEM; a static
-python loop walks column panels of width ``NB``.  Within a panel, columns
-are eliminated by masked rank-1 updates (VPU); the panel's trailing block
-row is recovered by a Neumann sweep of the nilpotent strictly-lower panel
-(``U12 = A12 - L11s @ U12`` iterated NB times, MXU matmuls); the trailing
-submatrix update ``A22 -= L21 @ U12`` is a single MXU matmul — the O(V^3)
-bulk of the factorization.
+python loop walks column panels of width ``NB`` (= 128, so every static
+slice is lane-aligned on TPU).  Within a panel, columns are eliminated by
+masked rank-1 updates (VPU); the panel's trailing block row is
+``U12 = (I + L11s)^{-1} A12`` with the inverse of the nilpotent
+strictly-lower panel from the log-depth Neumann product (MXU matmuls); the
+trailing submatrix update ``A22 -= L21 @ U12`` is a single MXU matmul —
+the O(V^3) bulk of the factorization.  Both are written through the
+output ref.
 """
 
 from __future__ import annotations
@@ -41,10 +43,14 @@ from jax.experimental import pallas as pl
 
 LANE = 128       # lane-dim alignment on real TPU
 SUBLANE = 8      # cheaper alignment used under interpret mode (tests/CPU)
-DEFAULT_NB = 32  # column-panel width of the blocked factorization
+DEFAULT_NB = LANE  # column-panel width: panels start lane-aligned on TPU
 
 # |U_ii| below this is treated as a structurally singular member.
 PIVOT_TINY = 1e-30
+
+# Every contraction here runs at full float32 precision: on TPU the default
+# matmul precision is a single bfloat16 pass, which would change the solves.
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 # ---------------------------------------------------------------------------
@@ -93,17 +99,21 @@ def _nilpotent_inv(X: jnp.ndarray) -> jnp.ndarray:
 
     Uses the log-depth product identity sum_{k<2^m} X^k =
     prod_j (I + X^(2^j)) — ceil(log2 nb) batched matmul rounds instead of
-    nb substitution steps.
+    nb substitution steps.  The leading dims are flattened into ONE batch
+    dim around the matmuls: XLA:CPU refuses to compile the multi-batch-dim
+    dot that the same einsum gives under ``vmap``.
     """
-    nb = X.shape[-1]
+    lead, nb = X.shape[:-2], X.shape[-1]
+    X = X.reshape((-1, nb, nb))
     eye = jnp.eye(nb, dtype=X.dtype)
+    mm = functools.partial(jnp.einsum, "bij,bjk->bik", precision=_HIGHEST)
     acc = eye + X
     span = 2
     while span < nb:
-        X = jnp.einsum("...ij,...jk->...ik", X, X)
-        acc = jnp.einsum("...ij,...jk->...ik", acc, eye + X)
+        X = mm(X, X)
+        acc = mm(acc, eye + X)
         span *= 2
-    return acc
+    return acc.reshape(lead + (nb, nb))
 
 
 def block_inverses(lu: jnp.ndarray, nb: int = REF_NB
@@ -157,8 +167,9 @@ def _block_subst(mat: jnp.ndarray, dinv: jnp.ndarray, b: jnp.ndarray,
         sl = slice(i * nb, (i + 1) * nb)
         panel = mat[:, sl, :]
         mask = (cols < i * nb) if lower else (cols >= (i + 1) * nb)
-        s = jnp.einsum("brv,bv->br", panel * mask, x)
-        x_i = jnp.einsum("brc,bc->br", dinv[:, i], b[:, sl] - s)
+        s = jnp.einsum("brv,bv->br", panel * mask, x, precision=_HIGHEST)
+        x_i = jnp.einsum("brc,bc->br", dinv[:, i], b[:, sl] - s,
+                         precision=_HIGHEST)
         x = x.at[:, sl].set(x_i)
     return x
 
@@ -180,8 +191,9 @@ def _subst_single(mat: jnp.ndarray, dinv: jnp.ndarray, b: jnp.ndarray,
     for i in order:
         sl = slice(i * nb, (i + 1) * nb)
         done = slice(0, i * nb) if lower else slice((i + 1) * nb, Vp)
-        s = mat[sl, done] @ x[done] if done.stop != done.start else 0.0
-        x = x.at[sl].set(dinv[i] @ (b[sl] - s))
+        s = (jnp.dot(mat[sl, done], x[done], precision=_HIGHEST)
+             if done.stop != done.start else 0.0)
+        x = x.at[sl].set(jnp.dot(dinv[i], b[sl] - s, precision=_HIGHEST))
     return x
 
 
@@ -285,12 +297,18 @@ def _pad_dim(V: int, interpret: bool) -> int:
 
 
 def _lu_kernel(a_ref, lu_ref, *, nb: int):
-    """Unpivoted blocked LU of one (Vp, Vp) matrix, in-register."""
+    """Unpivoted blocked LU of one (Vp, Vp) matrix.
+
+    Column panels of width ``nb`` start at multiples of ``nb`` (= the lane
+    width on TPU), so every static slice below is tile-aligned; the panel's
+    trailing block row and submatrix are written through the output ref.
+    """
     a = a_ref[0].astype(jnp.float32)
     Vp = a.shape[0]
     row = jax.lax.broadcasted_iota(jnp.int32, (Vp, Vp), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (Vp, Vp), 1)
-    vidx = jax.lax.broadcasted_iota(jnp.int32, (Vp,), 0)
+    rid = jax.lax.broadcasted_iota(jnp.int32, (Vp, 1), 0)
+    cid = jax.lax.broadcasted_iota(jnp.int32, (1, Vp), 1)
 
     for p0 in range(0, Vp, nb):
         p1 = min(p0 + nb, Vp)
@@ -299,82 +317,103 @@ def _lu_kernel(a_ref, lu_ref, *, nb: int):
             # Masked rank-1 elimination of column k, update restricted to
             # the panel's columns (the trailing block is updated once per
             # panel by the MXU matmul below).
-            piv = jnp.sum(jnp.where((row == k) & (col == k), a, 0.0))
-            colk = jnp.sum(jnp.where(col == k, a, 0.0), axis=1)      # (Vp,)
-            l = jnp.where(vidx > k, colk / piv, 0.0)
-            rowk = jnp.sum(jnp.where(row == k, a, 0.0), axis=0)      # (Vp,)
-            u = jnp.where((vidx > k) & (vidx < p1), rowk, 0.0)
-            a = a - l[:, None] * u[None, :]
+            rowk = jnp.sum(jnp.where(row == k, a, 0.0), axis=0, keepdims=True)
+            colk = jnp.sum(jnp.where(col == k, a, 0.0), axis=1, keepdims=True)
+            piv = jnp.sum(jnp.where(cid == k, rowk, 0.0), axis=1,
+                          keepdims=True)
+            l = jnp.where(rid > k, colk / piv, 0.0)                  # (Vp, 1)
+            u = jnp.where((cid > k) & (cid < p1), rowk, 0.0)         # (1, Vp)
+            a = a - l * u
             # store the multipliers below the diagonal of column k
-            return jnp.where((col == k) & (row > k), l[:, None], a)
+            return jnp.where((col == k) & (row > k), l, a)
 
         a = jax.lax.fori_loop(p0, p1, col_step, a)
 
         if p1 < Vp:
-            nb_p = p1 - p0
             L11 = a[p0:p1, p0:p1]
-            rloc = jax.lax.broadcasted_iota(jnp.int32, (nb_p, nb_p), 0)
-            cloc = jax.lax.broadcasted_iota(jnp.int32, (nb_p, nb_p), 1)
-            L11s = jnp.where(rloc > cloc, L11, 0.0)   # strictly lower, nilpotent
-            A12 = a[p0:p1, p1:]
-            # U12 = (I + L11s)^{-1} A12 via the finite Neumann fixed point
-            # (exact after nb_p sweeps since L11s^nb_p = 0) — MXU matmuls.
-            U12 = A12
-            for _ in range(nb_p):
-                U12 = A12 - jax.lax.dot(L11s, U12)
+            rloc = jax.lax.broadcasted_iota(jnp.int32, L11.shape, 0)
+            cloc = jax.lax.broadcasted_iota(jnp.int32, L11.shape, 1)
+            # U12 = (I + L11s)^{-1} A12, with the inverse of the unit-lower
+            # panel from the log-depth Neumann product (L11s is nilpotent).
+            inv = _nilpotent_panel_inv(-jnp.where(rloc > cloc, L11, 0.0))
+            U12 = jax.lax.dot(inv, a[p0:p1, p1:], precision=_HIGHEST)
             L21 = a[p1:, p0:p1]
-            a = a.at[p0:p1, p1:].set(U12)
-            a = a.at[p1:, p1:].add(-jax.lax.dot(L21, U12))
+            lu_ref[0] = a
+            lu_ref[0, p0:p1, p1:] = U12
+            lu_ref[0, p1:, p1:] = a[p1:, p1:] - jax.lax.dot(
+                L21, U12, precision=_HIGHEST)
+            a = lu_ref[0]
 
-    lu_ref[0, ...] = a.astype(lu_ref.dtype)
+    lu_ref[0] = a.astype(lu_ref.dtype)
 
 
-def _two_sweep(luw: jnp.ndarray, b: jnp.ndarray, *, trans: int) -> jnp.ndarray:
-    """In-kernel two-sweep substitution on a packed factor.
+def _nilpotent_panel_inv(X: jnp.ndarray) -> jnp.ndarray:
+    """In-kernel inv(I - X) of one nilpotent (n, n) panel (log-depth)."""
+    n = X.shape[-1]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+           ).astype(jnp.float32)
+    acc = eye + X
+    span = 2
+    while span < n:
+        X = jax.lax.dot(X, X, precision=_HIGHEST)
+        acc = jax.lax.dot(acc, eye + X, precision=_HIGHEST)
+        span *= 2
+    return acc
 
-    ``luw`` is the packed L\\U (already transposed by the caller when
-    trans=1); solves L U x = b (trans=0) or (L U)^T x = b (trans=1) — in
-    both cases a forward then a backward row sweep of ``luw``.
+
+def _two_sweep(row, b: jnp.ndarray, *, trans: int) -> jnp.ndarray:
+    """In-kernel two-sweep substitution on a packed L\\U factor.
+
+    ``row(i)`` reads row i of the packed factor, (1, Vp), from its ref;
+    ``b`` is (1, Vp).  Solves L U x = b (trans=0) or (L U)^T x = b
+    (trans=1).  Every sweep reads rows only: trans=0 takes the dot form
+    (row i of L, then of U), trans=1 the column-oriented (axpy) form, whose
+    column i of U^T / L^T is row i of U / L — so no transposed copy of the
+    factor is needed.
     """
-    Vp = luw.shape[0]
-    vidx = jax.lax.broadcasted_iota(jnp.int32, (Vp,), 0)
+    Vp = b.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, Vp), 1)
 
-    def row_of(m, i):
-        return jax.lax.dynamic_slice(m, (i, 0), (1, Vp))[0]
+    def pick(v, i):
+        return jnp.sum(jnp.where(lane == i, v, 0.0), axis=1, keepdims=True)
 
-    def diag_of(m, i):
-        return jnp.sum(jnp.where(vidx == i, row_of(m, i), 0.0))
+    if trans == 0:
+        def fwd(i, y):        # unit-lower L, dot form
+            s = jnp.sum(jnp.where(lane < i, row(i), 0.0) * y, axis=1,
+                        keepdims=True)
+            return jnp.where(lane == i, y - s, y)
 
-    # forward sweep: unit-lower L (trans=0) / lower-with-diag U^T (trans=1)
-    def fwd(i, y):
-        s = jnp.sum(jnp.where(vidx < i, row_of(luw, i), 0.0) * y)
-        d = diag_of(luw, i) if trans else 1.0
-        return jnp.where(vidx == i, (y - s) / d, y)
+        def bwd(j, x):        # upper U with diagonal, dot form
+            i = Vp - 1 - j
+            r = row(i)
+            s = jnp.sum(jnp.where(lane > i, r, 0.0) * x, axis=1,
+                        keepdims=True)
+            return jnp.where(lane == i, (x - s) / pick(r, i), x)
+    else:
+        def fwd(i, v):        # U^T (lower, diagonal of U), axpy form
+            r = row(i)
+            vi = pick(v, i) / pick(r, i)
+            return jnp.where(lane == i, vi,
+                             jnp.where(lane > i, v - vi * r, v))
+
+        def bwd(j, v):        # L^T (unit upper), axpy form
+            i = Vp - 1 - j
+            vi = pick(v, i)
+            return jnp.where(lane < i, v - vi * row(i), v)
 
     y = jax.lax.fori_loop(0, Vp, fwd, b)
-
-    # backward sweep: upper-with-diag U (trans=0) / unit-upper L^T (trans=1)
-    def bwd(j, x):
-        i = Vp - 1 - j
-        s = jnp.sum(jnp.where(vidx > i, row_of(luw, i), 0.0) * x)
-        d = 1.0 if trans else diag_of(luw, i)
-        return jnp.where(vidx == i, (x - s) / d, x)
-
     return jax.lax.fori_loop(0, Vp, bwd, y)
 
 
 def _solve_kernel(lu_ref, b_ref, x_ref, *, trans: int):
-    """Two-sweep substitution for one packed-LU system.
+    """Two-sweep substitution for one packed-LU system (trans=0: L U x = b,
+    trans=1: (L U)^T x = b)."""
+    def row(i):
+        return lu_ref[0, pl.ds(i, 1), :].astype(jnp.float32)
 
-    trans=0 solves L U x = b; trans=1 solves (L U)^T x = b, i.e. first the
-    lower-triangular U^T then the unit-upper L^T — both become row sweeps of
-    the transposed packed factor, so one upfront transpose unifies the code.
-    """
-    lu = lu_ref[0].astype(jnp.float32)
-    b = b_ref[0, 0].astype(jnp.float32)                          # (Vp,)
-    luw = lu.T if trans else lu
-    x = _two_sweep(luw, b, trans=trans)
-    x_ref[0, 0, ...] = x.astype(x_ref.dtype)
+    b = b_ref[0].astype(jnp.float32)                             # (1, Vp)
+    x_ref[0] = _two_sweep(row, b, trans=trans).astype(x_ref.dtype)
 
 
 def _chain_solve_kernel(lu_ref, base_ref, mult_ref, x_ref, *, trans: int,
@@ -393,22 +432,21 @@ def _chain_solve_kernel(lu_ref, base_ref, mult_ref, x_ref, *, trans: int,
     """
     Vp = lu_ref.shape[-1]
 
-    zero = jnp.int32(0)
-
     def body(j, carry):
         k = (K - 1 - j) if reverse else j
-        lu = pl.load(lu_ref, (zero, k, slice(None), slice(None))).astype(jnp.float32)
-        base_k = pl.load(base_ref, (zero, k, slice(None))).astype(jnp.float32)
-        mult_k = pl.load(mult_ref, (zero, k, slice(None))).astype(jnp.float32)
-        b = base_k + mult_k * carry
-        luw = lu.T if trans else lu
-        x = _two_sweep(luw, b, trans=trans)
+
+        def row(i):
+            return lu_ref[0, k, pl.ds(i, 1), :].astype(jnp.float32)
+
+        base_k = base_ref[0, pl.ds(k, 1), :].astype(jnp.float32)
+        mult_k = mult_ref[0, pl.ds(k, 1), :].astype(jnp.float32)
+        x = _two_sweep(row, base_k + mult_k * carry, trans=trans)
         if clamp:
             x = jnp.maximum(x, 0.0)
-        pl.store(x_ref, (zero, k, slice(None)), x.astype(x_ref.dtype))
+        x_ref[0, pl.ds(k, 1), :] = x.astype(x_ref.dtype)
         return x
 
-    jax.lax.fori_loop(0, K, body, jnp.zeros((Vp,), jnp.float32))
+    jax.lax.fori_loop(0, K, body, jnp.zeros((1, Vp), jnp.float32))
 
 
 # ---------------------------------------------------------------------------
@@ -512,6 +550,7 @@ def residuals(mats: jnp.ndarray, x: jnp.ndarray, rhs: jnp.ndarray,
     the GP loop consumes instead of per-solve exceptions (DESIGN.md §12).
     """
     op = jnp.einsum("bji,bj->bi" if trans else "bij,bj->bi",
-                    mats.astype(jnp.float32), x.astype(jnp.float32))
+                    mats.astype(jnp.float32), x.astype(jnp.float32),
+                    precision=_HIGHEST)
     r = jnp.max(jnp.abs(op - rhs), axis=-1) / (jnp.max(jnp.abs(rhs), axis=-1) + 1.0)
     return jnp.where(jnp.isfinite(r), r, jnp.inf)

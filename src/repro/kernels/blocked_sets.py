@@ -153,36 +153,47 @@ def _tagged_kernel(route_ref, imp_ref, out_ref):
     on the lane axis — sized for large V (the lane dim fills at V >= 4096);
     below that the packed-jnp path is preferred even on TPU, which
     ``kernels.ops.blocked_tagged`` encodes (DESIGN.md §13).
+
+    Words are int32 with the same bits as the uint32 packing (Mosaic reduces
+    signed integers only).  Re-packing the per-node flags (a sublane column)
+    into words (a lane row) is a masked sum over nodes: node p contributes
+    bit p % 32 to word p // 32, and a sum of distinct bits is their OR.
     """
-    route = route_ref[0]          # (Vp, W) uint32
+    route = route_ref[0]          # (Vp, W) int32
     imp = imp_ref[0]
     Vp, W = route.shape
-    weights = jnp.left_shift(jnp.uint32(1), jnp.arange(WORD, dtype=jnp.uint32))
+    node = jax.lax.broadcasted_iota(jnp.int32, (Vp, W), 0)
+    word = jax.lax.broadcasted_iota(jnp.int32, (Vp, W), 1)
+    place = jnp.where(node // WORD == word,
+                      jnp.left_shift(jnp.int32(1), node % WORD), 0)
 
     def round_(tb):
-        hit = imp | (route & tb[None, :])
-        tagged = jnp.any(hit != 0, axis=-1)                     # (Vp,)
-        tw = tagged.reshape(W, WORD).astype(jnp.uint32)
-        return jnp.sum(tw * weights, axis=-1, dtype=jnp.uint32)
+        hit = imp | (route & tb)
+        tagged = jnp.max(jnp.where(hit != 0, 1, 0), axis=1, keepdims=True)
+        return jnp.sum(place * tagged, axis=0, keepdims=True)       # (1, W)
 
+    # the bitset lives in the output block; the loop carries scalars only
     def cond(carry):
-        tb, prev, i = carry
-        return jnp.any(tb != prev) & (i < Vp + 1)
+        moved, i = carry
+        return moved & (i < Vp + 1)
 
     def body(carry):
-        tb, _, i = carry
-        return round_(tb), tb, i + 1
+        _, i = carry
+        tb = out_ref[0]
+        new = round_(tb)
+        out_ref[0] = new
+        return jnp.max(jnp.where(new != tb, 1, 0)) > 0, i + 1
 
-    tb0 = jnp.zeros((W,), jnp.uint32)
-    sentinel = jnp.full((W,), jnp.uint32(0xFFFFFFFF))
-    tb, _, _ = jax.lax.while_loop(cond, body, (tb0, sentinel, jnp.int32(0)))
-    out_ref[0, ...] = tb[None, :]
+    out_ref[0] = jnp.zeros((1, W), jnp.int32)
+    jax.lax.while_loop(cond, body, (jnp.bool_(True), jnp.int32(0)))
 
 
 def tagged_pallas(route_bits: jnp.ndarray, improper_bits: jnp.ndarray,
                   V: int, *, interpret: bool = False) -> jnp.ndarray:
     """Pallas path: (B, Vp, W) uint32 x2 -> (B, V) bool tagged flags."""
     B, Vp, W = route_bits.shape
+    as_i32 = functools.partial(jax.lax.bitcast_convert_type,
+                               new_dtype=jnp.int32)
     out = pl.pallas_call(
         _tagged_kernel,
         grid=(B,),
@@ -191,7 +202,8 @@ def tagged_pallas(route_bits: jnp.ndarray, improper_bits: jnp.ndarray,
             pl.BlockSpec((1, Vp, W), lambda b: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, W), lambda b: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 1, W), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((B, 1, W), jnp.int32),
         interpret=interpret,
-    )(route_bits, improper_bits)
-    return unpack_bits(out[:, 0, :], V)
+    )(as_i32(route_bits), as_i32(improper_bits))
+    words = jax.lax.bitcast_convert_type(out[:, 0, :], jnp.uint32)
+    return unpack_bits(words, V)

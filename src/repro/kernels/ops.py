@@ -1,8 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute with interpret=True; on a real
-TPU the same call sites compile to Mosaic.  ``INTERPRET`` flips automatically
-from the backend.
+On a TPU the kernels compile to Mosaic; on the CPU a kernel that a caller
+asks for runs with interpret=True.  ``INTERPRET`` follows the backend JAX
+starts with — there is no run-time fallback between the two.
 
 Shard-map contract (relied on by ``core/engine.py``, DESIGN.md §14): every
 wrapper here is *collective-free and per-member* — leading batch dims are
@@ -75,8 +75,8 @@ def ssd_chunk(xh, dt, dtA, cum, BH, CH):
 # Batched LU solve (the GP stage-system hot path — DESIGN.md §12)
 # ---------------------------------------------------------------------------
 #
-# Dispatch: on TPU the blocked Pallas kernels compile to Mosaic; on CPU the
-# "interpret-mode fallback" is engaged only when explicitly requested
+# Dispatch: on TPU the blocked Pallas kernels compile to Mosaic; on CPU
+# interpret mode is engaged only when explicitly requested
 # (``use_pallas=True`` — tests and kernel parity sweeps), because the
 # default CPU path should hit native batched LAPACK (``jax.lax.linalg.lu``)
 # rather than the Pallas interpreter.  Both paths share the packed-LU
@@ -104,9 +104,10 @@ class BatchedLU(NamedTuple):
 
 
 # The batched-LU kernels are written for Mosaic (TPU): VMEM-resident
-# arbitrary-size blocks, fori_loop row slicing.  On GPU the reference path
-# (cuBLAS/cuSOLVER batched LU via lax.linalg) is both safe and fast, so
-# Pallas engages by default only on TPU; interpret mode is for tests.
+# lane-padded blocks, rows read from refs with ``pl.ds``.  On GPU the
+# reference path (cuBLAS/cuSOLVER batched LU via lax.linalg) is both safe
+# and fast, so Pallas engages by default only on TPU; interpret mode is
+# for tests.
 _PALLAS_DEFAULT = jax.default_backend() == "tpu"
 
 

@@ -27,8 +27,8 @@ Two executable paths, dispatched by ``kernels.ops.sparse_chain_solve``:
   * :func:`chain_solve_bsr`  — the partition-blocked Pallas kernel: the
     stage matrices are gathered into BSR-style ``(NB, BD, bs, bs)`` blocks
     (``network.block_neighbors``) and the kernel iterates ONLY the nonzero
-    blocks — ``NB * BD`` dense ``bs x bs`` matmuls per sweep, MXU-shaped on
-    TPU (Mosaic; interpret mode for tests).
+    blocks — one ``(bs, BD*bs)`` block-row matmul per block row per sweep
+    on TPU (Mosaic; interpret mode for tests).
 
 Both compute the same linear map, so they agree to float tolerance; parity
 with the dense LU path on loop-free strategies is exact up to roundoff
@@ -42,6 +42,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Edge length of the partition blocks (``network.block_neighbors`` re-exports
 # this as ``network.SPARSE_BLOCK``): 32 matches both the bitset word width
@@ -53,6 +54,8 @@ SPARSE_BLOCK = 32
 # and freezing makes the while-loop exit instead of chasing a runaway
 # geometric series to the sweep cap.
 _DIVERGE = 1e12
+
+_LANE = 128  # TPU lane width
 
 
 def neighbor_values(phi_e: jnp.ndarray, nbr: jnp.ndarray, mask: jnp.ndarray,
@@ -162,56 +165,67 @@ def block_values(M: jnp.ndarray, blk_nbr: jnp.ndarray, blk_mask: jnp.ndarray,
     return jnp.where(blk_mask[:, :, None, None], bvals, 0.0)
 
 
-def _bsr_chain_kernel(nbr_ref, bvals_ref, base_ref, mult_ref, out_ref, *,
-                      reverse: bool, clamp: bool, cap: int):
-    """One flattened member per grid step; stage chain unrolled in-kernel.
+def _bsr_chain_kernel(nbr_ref, rows_ref, base_ref, mult_ref, out_ref,
+                      xprev_ref, x_ref, y_ref, b_ref, xc_ref, *,
+                      clamp: bool, cap: int):
+    """One (member, stage) per grid step, stages walked in chain order.
 
-    bvals (1, K, NB, BD, bs, bs), base/mult/out (1, K, Vp), nbr (NB, BD).
-    Each sweep touches only the NB*BD nonzero blocks — BD dense (bs, bs)
-    matmuls per block row, accumulated into the row block of the new
-    iterate.
+    rows (1, 1, NB, bs, BDp*bs): block row I of the stage matrix with its
+    BDp nonzero (bs, bs) blocks side by side; nbr (NB, BDp) in SMEM names
+    the column block of each.  Vectors are (Vp, 1) columns in VMEM scratch,
+    so every gather is a sublane-aligned row slice.  One sweep stacks the
+    BDp neighbour slices of x and takes ONE (bs, BDp*bs) @ (BDp*bs, 1)
+    matmul per block row.  ``xprev`` carries x_{k-1} across the stage axis
+    of the grid.
     """
-    K, NB, BD, bs = bvals_ref.shape[1:5]
-    Vp = NB * bs
+    NB, bs, W = rows_ref.shape[2:]
+    BDp = W // bs
 
-    def solve_stage(k: int, b):
-        bvals_k = bvals_ref[0, k]                        # (NB, BD, bs, bs)
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        xprev_ref[...] = jnp.zeros_like(xprev_ref)
 
-        def sweep(x):
-            rows = []
-            for I in range(NB):
-                acc = jax.lax.dynamic_slice(b, (I * bs,), (bs,))
-                for d in range(BD):
-                    J = nbr_ref[I, d]
-                    xj = jax.lax.dynamic_slice(x, (J * bs,), (bs,))
-                    acc = acc + bvals_k[I, d] @ xj
-                rows.append(acc)
-            y = jnp.concatenate(rows)
+    b_ref[...] = base_ref[0, 0] + mult_ref[0, 0] * xprev_ref[...]
+
+    def sweep():
+        """y <- b + M x, with diverging entries latched at +inf."""
+        def row_block(I, carry):
+            for d in range(BDp):
+                j0 = pl.multiple_of(nbr_ref[I, d] * bs, bs)
+                xc_ref[d * bs:(d + 1) * bs, :] = x_ref[pl.ds(j0, bs), :]
+            r0 = pl.multiple_of(I * bs, bs)
+            y = b_ref[pl.ds(r0, bs), :] + jax.lax.dot(
+                rows_ref[0, 0, I], xc_ref[...],
+                precision=jax.lax.Precision.HIGHEST)
             bad = ~jnp.isfinite(y) | (jnp.abs(y) > _DIVERGE)
-            return jnp.where(bad, jnp.inf, y)
+            y_ref[pl.ds(r0, bs), :] = jnp.where(bad, jnp.inf, y)
+            return carry
 
-        def cond(carry):
-            x, prev, i = carry
-            return jnp.any(x != prev) & (i < cap)
+        jax.lax.fori_loop(0, NB, row_block, 0)
 
-        def body(carry):
-            x, _, i = carry
-            return sweep(x), x, i + 1
+    def changed(prev):
+        return jnp.max(jnp.where(y_ref[...] != prev, 1, 0)) > 0
 
-        x0 = sweep(jnp.zeros((Vp,), b.dtype))
-        prev0 = jnp.full((Vp,), jnp.inf, b.dtype)
-        x, _, _ = jax.lax.while_loop(cond, body, (x0, prev0, jnp.int32(1)))
-        return x
+    def cond(carry):
+        moved, i = carry
+        return moved & (i < cap)
 
-    ks = range(K - 1, -1, -1) if reverse else range(K)
-    x_prev = jnp.zeros((Vp,), base_ref.dtype)
-    for k in ks:
-        b = base_ref[0, k] + mult_ref[0, k] * x_prev
-        x = solve_stage(k, b)
-        if clamp:
-            x = jnp.maximum(x, 0.0)
-        out_ref[0, k, :] = x
-        x_prev = x
+    def body(carry):
+        _, i = carry
+        x_ref[...] = y_ref[...]
+        sweep()
+        return changed(x_ref[...]), i + 1
+
+    # x_0 = sweep(0), compared against an all-inf "previous" iterate
+    x_ref[...] = jnp.zeros_like(x_ref)
+    sweep()
+    moved0 = changed(jnp.full(x_ref.shape, jnp.inf, jnp.float32))
+    jax.lax.while_loop(cond, body, (moved0, jnp.int32(1)))
+    x = y_ref[...]
+    if clamp:
+        x = jnp.maximum(x, 0.0)
+    out_ref[0, 0] = x
+    xprev_ref[...] = x
 
 
 def chain_solve_bsr(bvals: jnp.ndarray, blk_nbr: jnp.ndarray,
@@ -222,31 +236,46 @@ def chain_solve_bsr(bvals: jnp.ndarray, blk_nbr: jnp.ndarray,
 
     bvals (B, K, NB, BD, bs, bs) from :func:`block_values`, blk_nbr (NB, BD),
     base/mult (B, K, V) -> x (B, K, V); same semantics as
-    :func:`chain_solve_nbr`.
+    :func:`chain_solve_nbr`.  The block degree is padded with zero blocks
+    to BDp so that a block row (bs, BDp*bs) spans whole 128-lane tiles.
     """
     B, K, NB, BD, bs = bvals.shape[:5]
     Vp = NB * bs
     V = base.shape[-1]
-    if Vp != V:
-        widths = ((0, 0), (0, 0), (0, Vp - V))
-        base = jnp.pad(base, widths)
-        mult = jnp.pad(mult, widths)
-    kernel = functools.partial(_bsr_chain_kernel, reverse=reverse,
-                               clamp=clamp, cap=V + 2)
+    BDp = -(-BD * bs // _LANE) * _LANE // bs
+    if BDp != BD:
+        bvals = jnp.pad(bvals, ((0, 0),) * 3 + ((0, BDp - BD), (0, 0), (0, 0)))
+        blk_nbr = jnp.pad(blk_nbr, ((0, 0), (0, BDp - BD)))
+    rows = jnp.swapaxes(bvals, 3, 4).reshape(B, K, NB, bs, BDp * bs)
+    widths = ((0, 0), (0, 0), (0, Vp - V))
+    base = jnp.pad(base.astype(jnp.float32), widths)[..., None]
+    mult = jnp.pad(mult.astype(jnp.float32), widths)[..., None]
+
+    def stage(b, k, nbr):
+        return (b, K - 1 - k) if reverse else (b, k)
+
+    vec = pl.BlockSpec((1, 1, Vp, 1),
+                       lambda b, k, nbr: stage(b, k, nbr) + (0, 0))
     out = pl.pallas_call(
-        kernel,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((NB, BD), lambda b: (0, 0)),
-            pl.BlockSpec((1, K, NB, BD, bs, bs), lambda b: (b, 0, 0, 0, 0, 0)),
-            pl.BlockSpec((1, K, Vp), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, K, Vp), lambda b: (b, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, K, Vp), lambda b: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, K, Vp), base.dtype),
+        functools.partial(_bsr_chain_kernel, clamp=clamp, cap=V + 2),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, K),
+            in_specs=[
+                pl.BlockSpec((1, 1, NB, bs, BDp * bs),
+                             lambda b, k, nbr: stage(b, k, nbr) + (0, 0, 0)),
+                vec, vec,
+            ],
+            out_specs=vec,
+            scratch_shapes=[pltpu.VMEM((Vp, 1), jnp.float32)] * 4
+            + [pltpu.VMEM((BDp * bs, 1), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, K, Vp, 1), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(blk_nbr, bvals, base, mult)
-    return out[..., :V]
+    )(blk_nbr.astype(jnp.int32), rows.astype(jnp.float32), base, mult)
+    return out[..., :V, 0]
 
 
 # ---------------------------------------------------------------------------

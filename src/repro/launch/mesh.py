@@ -14,11 +14,9 @@ import jax
 def _make_mesh(shape, axes):
     # NOT repro.core.compat.make_mesh: importing repro.core would build
     # module-level jnp constants and initialize the backend, which this
-    # module must never do (see module docstring).  Same fallback, inline.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    # module must never do (see module docstring).
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

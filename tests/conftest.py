@@ -3,6 +3,7 @@ import os
 import sys
 
 import pytest
+from hypothesis import settings
 
 # Make `import repro` work regardless of how pytest is invoked.
 _SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
@@ -14,18 +15,11 @@ if _SRC not in sys.path:
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 # Property tests must draw the same examples on every run and every machine
-# (tier-1 regressions are diffed across commits).  When the real hypothesis
-# is installed, register and load a derandomized profile; the fallback shim
-# in tests/_hypothesis_compat.py is deterministic by construction.
-try:
-    from hypothesis import settings as _hyp_settings
-
-    _hyp_settings.register_profile(
-        "repro-deterministic", derandomize=True, deadline=None,
-        print_blob=False)
-    _hyp_settings.load_profile("repro-deterministic")
-except ModuleNotFoundError:
-    pass
+# (tier-1 regressions are diffed across commits): load a derandomized
+# hypothesis profile.
+settings.register_profile("repro-deterministic", derandomize=True,
+                          deadline=None, print_blob=False)
+settings.load_profile("repro-deterministic")
 
 
 @pytest.fixture(autouse=True, scope="module")

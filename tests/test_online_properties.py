@@ -13,9 +13,8 @@ The online service leans on three mechanical guarantees:
     scan on randomized congested strategies (the fused hot path cannot
     silently diverge from Section IV's definition).
 
-Randomization goes through ``tests/_hypothesis_compat`` — real
-``hypothesis`` when installed, the deterministic fallback otherwise — so
-tier-1 runs the same examples everywhere.
+Randomization goes through ``hypothesis`` under the derandomized profile
+that ``conftest.py`` loads, so tier-1 runs the same examples everywhere.
 """
 
 import jax
@@ -24,7 +23,7 @@ import numpy as np
 import pytest
 
 from repro.core import gp, marginals, network, traffic
-from tests._hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 
 # One compile each (shapes are fixed across examples): the eager accel

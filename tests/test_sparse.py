@@ -19,6 +19,7 @@ import pytest
 from repro.core import batch, compat, distributed, engine, gp, network
 from repro.core import marginals as marginals_mod
 from repro.core import traffic
+from repro.kernels import ops
 
 KW = dict(alpha=0.1, max_iters=40, patience=10**6, tol=0.0)
 
@@ -71,6 +72,28 @@ def test_pdt_recursion_sparse_matches_dense(name):
     pdt_d = marginals_mod.pdt_recursion(inst, phi, Dp, Cp,
                                         solver="batched_lu")
     assert _rel(pdt_d, pdt_s) <= 1e-5
+
+
+@pytest.mark.parametrize("trans,reverse,clamp",
+                         [(1, False, False), (0, True, True)],
+                         ids=["traffic", "marginals"])
+@pytest.mark.parametrize("name", ["geant", "sw-queue"])
+def test_bsr_chain_solve_matches_nbr(name, trans, reverse, clamp):
+    """The partition-blocked Pallas chain solve (interpret mode) computes
+    the same chain as the neighbor-list jnp sweeps, for both sweep shapes."""
+    inst = _sparse_inst(name)
+    phi = gp.init_phi(inst)
+    topo = ops.sparse_topo(inst)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(inst.V))
+    base = jax.random.uniform(k1, phi.c.shape) - (0.5 if clamp else 0.0)
+    mult = jax.random.uniform(k2, phi.c.shape)
+    kw = dict(trans=trans, reverse=reverse, clamp=clamp)
+    got = ops.sparse_chain_solve(topo, phi.e, base, mult, use_pallas=True,
+                                 **kw)
+    want = ops.sparse_chain_solve(topo, phi.e, base, mult, use_pallas=False,
+                                  **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
 
 
 @pytest.mark.parametrize("name", SCENARIOS)

@@ -1,0 +1,118 @@
+"""Compile the main-path Pallas kernels for a described TPU v5e chip.
+
+Nothing runs here: each test lowers and compiles one kernel at the sizes
+the solver uses, for a v5e device that is described, not attached.  The
+TPU compiler refuses what interpret mode accepts — slices that are not
+tile-aligned, primitives Mosaic cannot lower, more VMEM than a kernel may
+use — so these tests guard the chip path without a chip.  Each asserts
+that the compiled program holds the kernel (``tpu_custom_call``).
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process at a time may load the TPU library.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import network
+from repro.kernels import batched_solve as bs
+from repro.kernels import blocked_sets as bset
+from repro.kernels import sparse_solve as ss
+
+# the stepsize-ladder width the engine vmaps the traffic solve over
+LADDER = 12
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import compilation_cache, topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _compile_text(fn, sharding, *args):
+    """Compile ``fn`` for the described chip; args are (shape, dtype)."""
+    specs = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in args]
+    return jax.jit(fn).lower(*specs).compile().as_text()
+
+
+F32 = jnp.float32
+
+
+@pytest.mark.parametrize("V,B", [(100, 30 * 3), (300, 3 * 3)],
+                         ids=["table2-Vp128", "metro-dense-Vp384"])
+def test_lu_factor_compiles(one_chip, V, B):
+    fn = jax.vmap(functools.partial(bs.lu_factor, interpret=False))
+    text = _compile_text(fn, one_chip, ((LADDER, B, V, V), F32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("trans", [0, 1])
+def test_lu_solve_compiles(one_chip, trans):
+    fn = functools.partial(bs.lu_solve, trans=trans, interpret=False)
+    text = _compile_text(fn, one_chip, ((90, 100, 100), F32), ((90, 100), F32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("trans,reverse,clamp",
+                         [(1, False, False), (0, True, True)],
+                         ids=["traffic", "marginals"])
+@pytest.mark.parametrize("V,A", [(100, 30), (300, 3)],
+                         ids=["Vp128", "Vp384"])
+def test_chain_solve_compiles(one_chip, V, A, trans, reverse, clamp):
+    K = 3
+    fn = jax.vmap(functools.partial(bs.chain_solve, trans=trans,
+                                    reverse=reverse, clamp=clamp,
+                                    interpret=False))
+    text = _compile_text(fn, one_chip, ((LADDER, A, K, V, V), F32),
+                         ((LADDER, A, K, V), F32), ((LADDER, A, K, V), F32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("V", [300, 1000], ids=["metro-sw-300",
+                                                "metro-sw-1000"])
+@pytest.mark.parametrize("reverse,clamp", [(False, False), (True, True)],
+                         ids=["traffic", "marginals"])
+def test_chain_solve_bsr_compiles(one_chip, V, reverse, clamp):
+    inst = network.metro_instance("sw", V)
+    A, K = inst.r.shape[0], inst.stage_mask.shape[1]
+    blk_nbr = np.asarray(inst.blk_nbr)
+    NB, BD = blk_nbr.shape
+    bsz = ss.SPARSE_BLOCK
+
+    def fn(bvals, base, mult):
+        return ss.chain_solve_bsr(bvals, jnp.asarray(blk_nbr), base, mult,
+                                  reverse=reverse, clamp=clamp,
+                                  interpret=False)
+
+    text = _compile_text(jax.vmap(fn), one_chip,
+                         ((LADDER, A, K, NB, BD, bsz, bsz), F32),
+                         ((LADDER, A, K, V), F32), ((LADDER, A, K, V), F32))
+    assert "tpu_custom_call" in text
+
+
+def test_tagged_pallas_compiles(one_chip):
+    V, B = 4096, 9
+    Vp, W = bset.padded_nodes(V)
+    fn = functools.partial(bset.tagged_pallas, V=V, interpret=False)
+    text = _compile_text(fn, one_chip, ((B, Vp, W), jnp.uint32),
+                         ((B, Vp, W), jnp.uint32))
+    assert "tpu_custom_call" in text
