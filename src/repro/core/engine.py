@@ -488,9 +488,10 @@ def gp_step(
     best = jnp.argmin(cand_costs)
     new_phi = jax.tree_util.tree_map(lambda x: x[best], cands)
 
-    # residual of sufficiency condition (6) at the *new* iterate, computed
-    # cheaply from the current marginals (exact residual is recomputed by
-    # the caller when it matters)
+    # residual of sufficiency condition (6) at the *incoming* iterate
+    # ``phi``, from its marginals ``m`` (already at hand), not at the
+    # ``new_phi`` this step returns; so a solve that stops on it returns the
+    # strategy one step past the one it certified
     if accel is not None and accel.residual_stop:
         # exact conditions.sufficiency_residual form: the minimum is taken
         # over *all* directions, not the blocked-masked set, so the latch

@@ -40,6 +40,7 @@ from repro.core import engine
 from repro.core.engine import GPState, ScanCarry as _ScanCarry
 from repro.core.network import Instance
 from repro.core.traffic import Phi, renormalize, total_cost
+from repro.obs.spans import span
 
 # Historical spellings, re-exported for call sites and differential tests
 # that predate the engine extraction.
@@ -401,37 +402,47 @@ def solve(
     :class:`engine.AccelConfig`) enables the §15 acceleration layer.
     ``app_mask`` ((A,) bool) freezes applications (the §16 skip gate):
     frozen apps keep their phi rows and still contribute their flows to
-    the shared F/G measurement, and the residual stop ignores them."""
+    the shared F/G measurement, and the residual stop ignores them.
+
+    Under a running ``jax.profiler`` trace the call and its host phases are
+    named spans on the device's clock (``repro.obs.spans.span``):
+    ``gp.solve``, and inside it ``gp.solve.init`` (initial strategy and
+    carry), one ``gp.solve.dispatch`` per chunk, and ``gp.solve.trim``."""
     del track_every
-    accel = engine.resolve_accel(accel)
-    telemetry = engine.resolve_telemetry(telemetry)
-    phi = phi0 if phi0 is not None else init_phi(inst)
-    carry = _init_carry(inst, phi, accel=accel, telemetry=telemetry)
-    cost0 = carry.cost
-    alpha_, tol_ = jnp.float32(alpha), jnp.float32(tol)
-    patience_, max_iters_ = jnp.int32(patience), jnp.int32(max_iters)
-    cost_chunks, res_chunks = [], []
-    steps = 0
-    while steps < max_iters:
-        carry, (cs, rs) = _scan_chunk(
-            inst, carry, alpha_, tol_, patience_, max_iters_,
-            allowed_e, allowed_c,
-            length=min(_SOLVE_CHUNK, max_iters - steps), scaled=scaled,
-            solver=solver, blocked=blocked, accel=accel, app_mask=app_mask,
-            telemetry=telemetry,
-        )
-        cost_chunks.append(cs)
-        res_chunks.append(rs)
-        steps += len(cs)
-        if bool(carry.done):
-            break
-    return GPResult(
-        phi=carry.phi,
-        cost_history=jnp.concatenate([cost0[None], *cost_chunks]),
-        residual_history=jnp.concatenate(res_chunks) if res_chunks else jnp.zeros((0,)),
-        iterations=int(carry.iters),
-        telemetry=carry.tb if telemetry is not None else None,
-    ).trim()
+    with span("gp.solve"):
+        accel = engine.resolve_accel(accel)
+        telemetry = engine.resolve_telemetry(telemetry)
+        with span("gp.solve.init"):
+            phi = phi0 if phi0 is not None else init_phi(inst)
+            carry = _init_carry(inst, phi, accel=accel, telemetry=telemetry)
+        cost0 = carry.cost
+        alpha_, tol_ = jnp.float32(alpha), jnp.float32(tol)
+        patience_, max_iters_ = jnp.int32(patience), jnp.int32(max_iters)
+        cost_chunks, res_chunks = [], []
+        steps = 0
+        while steps < max_iters:
+            with span("gp.solve.dispatch"):
+                carry, (cs, rs) = _scan_chunk(
+                    inst, carry, alpha_, tol_, patience_, max_iters_,
+                    allowed_e, allowed_c,
+                    length=min(_SOLVE_CHUNK, max_iters - steps),
+                    scaled=scaled, solver=solver, blocked=blocked,
+                    accel=accel, app_mask=app_mask, telemetry=telemetry,
+                )
+            cost_chunks.append(cs)
+            res_chunks.append(rs)
+            steps += len(cs)
+            if bool(carry.done):
+                break
+        with span("gp.solve.trim"):
+            return GPResult(
+                phi=carry.phi,
+                cost_history=jnp.concatenate([cost0[None], *cost_chunks]),
+                residual_history=(jnp.concatenate(res_chunks) if res_chunks
+                                  else jnp.zeros((0,))),
+                iterations=int(carry.iters),
+                telemetry=carry.tb if telemetry is not None else None,
+            ).trim()
 
 
 @functools.partial(jax.jit,

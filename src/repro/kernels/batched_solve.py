@@ -471,6 +471,7 @@ def lu_factor(mats: jnp.ndarray, *, nb: int = DEFAULT_NB,
         out_specs=pl.BlockSpec((1, Vp, Vp), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Vp, Vp), jnp.float32),
         interpret=interpret,
+        name="lu_factor",
     )(a)
     return out[:, :V, :V]
 
@@ -493,6 +494,7 @@ def lu_solve(lu: jnp.ndarray, rhs: jnp.ndarray, *, trans: int = 0,
         out_specs=pl.BlockSpec((1, 1, Vp), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, 1, Vp), jnp.float32),
         interpret=interpret,
+        name="lu_solve",
     )(a, b[:, None, :])
     return out[:, 0, :V]
 
@@ -525,6 +527,7 @@ def chain_solve(lu: jnp.ndarray, base: jnp.ndarray, mult: jnp.ndarray,
         out_specs=pl.BlockSpec((1, K, Vp), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, K, Vp), jnp.float32),
         interpret=interpret,
+        name="chain_solve",
     )(a, basep, multp)
     return out[:, :, :V]
 

@@ -274,6 +274,7 @@ def chain_solve_bsr(bvals: jnp.ndarray, blk_nbr: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="chain_solve_bsr",
     )(blk_nbr.astype(jnp.int32), rows.astype(jnp.float32), base, mult)
     return out[..., :V, 0]
 

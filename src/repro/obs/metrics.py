@@ -6,7 +6,7 @@ inside the device programs: skip-gate hits, escalation-rung climbs, LKG
 rollbacks, quarantines, fault injections, compile-cache traffic.
 
 Names are dot-separated (``online.gate.skip``, ``faults.injected.nan_carry``)
-so exports group naturally.  Exports are plain JSON / JSONL; the span layer
+so exports group naturally.  The export is plain JSON; the span layer
 (:mod:`repro.obs.spans`) mirrors counters into Chrome-trace ``"C"`` events
 when a tracer is attached.
 """
@@ -64,20 +64,6 @@ class Metrics:
     def export_json(self, path: str) -> None:
         with open(path, "w") as f:
             json.dump(self.snapshot(), f, indent=1, sort_keys=True)
-
-    def export_jsonl(self, path: str) -> None:
-        """One line per metric — the stream-friendly export."""
-        with open(path, "w") as f:
-            for name, v in sorted(self.counters.items()):
-                f.write(json.dumps(
-                    {"kind": "counter", "name": name, "value": v}) + "\n")
-            for name, v in sorted(self.gauges.items()):
-                f.write(json.dumps(
-                    {"kind": "gauge", "name": name, "value": v}) + "\n")
-            for name, vals in sorted(self.histograms.items()):
-                f.write(json.dumps(
-                    {"kind": "histogram", "name": name,
-                     **self._summary(vals)}) + "\n")
 
 
 def collect_compile_caches(metrics: Optional[Metrics]) -> dict:

@@ -17,9 +17,15 @@ chrome://tracing both load):
 Spans nest per (pid, tid) by plain stack discipline: the exporter emits
 them as complete events and the viewer reconstructs the nesting from
 containment, so the only requirement is that a child closes before its
-parent (guaranteed by the context manager).  The JSONL export mirrors the
-same records one-per-line for programmatic consumers
-(:mod:`repro.obs.report`).
+parent (guaranteed by the context manager).  :mod:`repro.obs.report`
+reads the ``events.jsonl`` that ``benchmarks/online_bench.py`` writes,
+and this Chrome trace where one was exported beside it.
+
+Every span also lands in a running ``jax.profiler`` trace, on the same
+clock as the device's operations (:func:`span`); with no profiler running
+that records nothing.  The solver's drivers use :func:`span` directly
+(``gp.solve`` and its phases), so a profiled run can attribute each gap in
+the device's work to the host phase that left it.
 """
 
 from __future__ import annotations
@@ -28,6 +34,15 @@ import contextlib
 import json
 import time
 from typing import Optional
+
+import jax
+
+
+def span(name: str):
+    """Context manager naming a host phase in a running ``jax.profiler``
+    trace (a ``TraceAnnotation``, on the device trace's clock); it records
+    nothing when no profiler is running."""
+    return jax.profiler.TraceAnnotation(name)
 
 
 class Tracer:
@@ -58,7 +73,8 @@ class Tracer:
         rec["depth"] = len(stack)
         stack.append(rec)
         try:
-            yield rec
+            with span(name):
+                yield rec
         finally:
             rec["dur"] = self._us() - rec["ts"]
             stack.pop()
@@ -102,11 +118,6 @@ class Tracer:
     def export_chrome(self, path: str, **kw) -> None:
         with open(path, "w") as f:
             json.dump(self.to_chrome(**kw), f, indent=1)
-
-    def export_jsonl(self, path: str) -> None:
-        with open(path, "w") as f:
-            for e in sorted(self.events, key=lambda e: e["ts"]):
-                f.write(json.dumps(e) + "\n")
 
 
 def load_chrome(path: str) -> list[dict]:

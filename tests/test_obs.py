@@ -249,15 +249,89 @@ def test_metrics_registry(tmp_path):
     h = snap["histograms"]["h"]
     assert h["count"] == 10 and h["min"] == 0.0 and h["max"] == 9.0
     assert h["p50"] == 4.0
-    path = str(tmp_path / "m.jsonl")
-    m.export_jsonl(path)
-    kinds = [json.loads(line)["kind"] for line in open(path)]
-    assert kinds == ["counter", "gauge", "histogram"]
+    path = str(tmp_path / "m.json")
+    m.export_json(path)
+    with open(path) as f:
+        assert json.load(f) == json.loads(json.dumps(snap))
 
 
 def test_collect_compile_caches():
     out = obs.collect_compile_caches(None)
     assert "compile.mesh_chunk.entries" in out
+
+
+# ---------------------------------------------------------------------------
+# profiler spans (gp.solve's host phases)
+# ---------------------------------------------------------------------------
+
+# two solves: one that runs past its first 32-iteration chunk, and one
+# whose tolerance every residual meets, which stops after one chunk
+PROFILED = (dict(alpha=0.1, max_iters=40, patience=10**6, tol=0.0),
+            dict(alpha=0.1, max_iters=40, patience=10**6, tol=1e9))
+
+
+def _solve_all():
+    return [gp.solve(_inst(), accel=True, **kw) for kw in PROFILED]
+
+
+def _host_spans(log_dir):
+    """[name, start_ns, end_ns] of the host events a profiler trace holds."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    return [[ev.name, ev.start_ns, ev.start_ns + ev.duration_ns]
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """The two solves with the profiler off, then on; the trace's spans."""
+    off = _solve_all()
+    log_dir = str(tmp_path_factory.mktemp("profile"))
+    jax.profiler.start_trace(log_dir)
+    try:
+        on = _solve_all()
+        with obs.Tracer().span("event:probe"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    return off, on, _host_spans(log_dir)
+
+
+def test_gp_solve_spans_one_per_call_and_chunk(profiled):
+    off, _, spans = profiled
+    chunks = [-(-r.iterations // gp._SOLVE_CHUNK) for r in off]
+    assert chunks == [2, 1]
+    count = {n: sum(1 for s in spans if s[0] == n) for n in (
+        "gp.solve", "gp.solve.init", "gp.solve.dispatch", "gp.solve.trim",
+        "event:probe")}
+    assert count == {"gp.solve": 2, "gp.solve.init": 2,
+                     "gp.solve.dispatch": sum(chunks), "gp.solve.trim": 2,
+                     "event:probe": 1}
+    # every phase lies inside a gp.solve span, and each solve holds its
+    # own chunks' dispatches
+    solves = sorted(s[1:] for s in spans if s[0] == "gp.solve")
+    for n, t0, t1 in spans:
+        if n.startswith("gp.solve."):
+            assert any(a <= t0 and t1 <= b for a, b in solves), n
+    for (a, b), k in zip(solves, chunks):
+        assert sum(1 for n, t0, _ in spans
+                   if n == "gp.solve.dispatch" and a <= t0 <= b) == k
+
+
+def test_profiler_leaves_gp_solve_bit_identical(profiled):
+    off, on, _ = profiled
+    for r0, r1 in zip(off, on):
+        assert r0.iterations == r1.iterations
+        for a, b in [(r0.phi.e, r1.phi.e), (r0.phi.c, r1.phi.c),
+                     (r0.cost_history, r1.cost_history),
+                     (r0.residual_history, r1.residual_history)]:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ---------------------------------------------------------------------------

@@ -13,6 +13,7 @@ module is imported: only one process at a time may load the TPU library.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +55,15 @@ def _compile_text(fn, sharding, *args):
     return jax.jit(fn).lower(*specs).compile().as_text()
 
 
+def _kernel_named(text, name):
+    """The compiled program holds the kernel as an instruction named after
+    it, the name a profiler trace gives the kernel's device operations (a
+    kernel vmapped outside any jit, as here, is named ``vmap_<name>_``)."""
+    return re.search(rf"^\s*%(vmap_)?{name}_?(\.\d+)? = \S+ custom-call\(.*"
+                     r'custom_call_target="tpu_custom_call"', text,
+                     re.MULTILINE) is not None
+
+
 F32 = jnp.float32
 
 
@@ -62,14 +72,14 @@ F32 = jnp.float32
 def test_lu_factor_compiles(one_chip, V, B):
     fn = jax.vmap(functools.partial(bs.lu_factor, interpret=False))
     text = _compile_text(fn, one_chip, ((LADDER, B, V, V), F32))
-    assert "tpu_custom_call" in text
+    assert _kernel_named(text, "lu_factor")
 
 
 @pytest.mark.parametrize("trans", [0, 1])
 def test_lu_solve_compiles(one_chip, trans):
     fn = functools.partial(bs.lu_solve, trans=trans, interpret=False)
     text = _compile_text(fn, one_chip, ((90, 100, 100), F32), ((90, 100), F32))
-    assert "tpu_custom_call" in text
+    assert _kernel_named(text, "lu_solve")
 
 
 @pytest.mark.parametrize("trans,reverse,clamp",
@@ -84,7 +94,7 @@ def test_chain_solve_compiles(one_chip, V, A, trans, reverse, clamp):
                                     interpret=False))
     text = _compile_text(fn, one_chip, ((LADDER, A, K, V, V), F32),
                          ((LADDER, A, K, V), F32), ((LADDER, A, K, V), F32))
-    assert "tpu_custom_call" in text
+    assert _kernel_named(text, "chain_solve")
 
 
 @pytest.mark.parametrize("V", [300, 1000], ids=["metro-sw-300",
@@ -106,7 +116,7 @@ def test_chain_solve_bsr_compiles(one_chip, V, reverse, clamp):
     text = _compile_text(jax.vmap(fn), one_chip,
                          ((LADDER, A, K, NB, BD, bsz, bsz), F32),
                          ((LADDER, A, K, V), F32), ((LADDER, A, K, V), F32))
-    assert "tpu_custom_call" in text
+    assert _kernel_named(text, "chain_solve_bsr")
 
 
 def test_tagged_pallas_compiles(one_chip):
