@@ -665,12 +665,15 @@ def scan_chunk(
 ):
     """Advance the solve by up to ``length`` iterations entirely on device.
 
-    Early-stop is a *mask*, not a break: once ``done`` latches (residual
-    below tol, ladder-stationary for ``patience`` iterations, the
-    ``max_iters`` budget spent, or — with ``accel.residual_stop`` — a
-    committed positive-stepsize move below ``accel.phi_tol``) the carry is
-    frozen and subsequent steps re-emit the converged (cost, residual),
-    keeping history shapes static.
+    Once ``done`` latches (residual below tol, ladder-stationary for
+    ``patience`` iterations, the ``max_iters`` budget spent, or — with
+    ``accel.residual_stop`` — a committed positive-stepsize move below
+    ``accel.phi_tol``) the carry is frozen: the chunk's step loop (a
+    ``lax.while_loop`` that tests the latch) exits, so no step after the
+    latch runs, and those steps re-emit the converged (cost, residual),
+    which keeps history shapes static.  Under ``jax.vmap`` (batched
+    families, the mesh driver's member axis) the latch is batched: the loop
+    runs while any member is live, and latched members keep their carry.
 
     With ``accel`` set the body additionally runs the §15 layer: the plain
     step seeds an Anderson candidate from the carry's history window, the
@@ -689,7 +692,7 @@ def scan_chunk(
     use_phistop = (accel is not None and accel.residual_stop
                    and accel.phi_tol >= 0)
 
-    def body(c: ScanCarry, _):
+    def body(c: ScanCarry) -> ScanCarry:
         if use_adaptive:
             # carry alpha 0 = unseeded (first iteration / legacy warm
             # start): adopt the driver's alpha argument.
@@ -735,6 +738,10 @@ def scan_chunk(
             af = _push_history(af, f_k)
             ak = jnp.minimum(ak + 1, jnp.int32(accel.anderson_m))
 
+        # The loop below exits at the latch, so a step never runs frozen
+        # (under vmap the loop's own select keeps latched members).  These
+        # selects stay all the same: without them the TPU compiler builds
+        # a different step, whose answers differ in their last bits.
         frz = c.done
         phi = jax.tree_util.tree_map(
             lambda new, old: jnp.where(frz, old, new), new_phi, c.phi)
@@ -798,9 +805,23 @@ def scan_chunk(
             ])
             tb = ring_record(tb, c.iters, row, ~frz)
 
-        nc = ScanCarry(phi=phi, best_cost=best, stall=stall, done=done,
-                       iters=iters, cost=cost, residual=residual,
-                       alpha=new_alpha, ax=ax, af=af, ak=ak, tb=tb)
-        return nc, (cost, residual)
+        return ScanCarry(phi=phi, best_cost=best, stall=stall, done=done,
+                         iters=iters, cost=cost, residual=residual,
+                         alpha=new_alpha, ax=ax, af=af, ak=ak, tb=tb)
 
-    return jax.lax.scan(body, carry, None, length=length)
+    def running(st):
+        i, c, _, _ = st
+        return (i < length) & ~c.done
+
+    def step(st):
+        i, c, cs, rs = st
+        c = body(c)
+        return i + 1, c, cs.at[i].set(c.cost), rs.at[i].set(c.residual)
+
+    hist = jnp.zeros((length,), jnp.float32)
+    n, carry, cs, rs = jax.lax.while_loop(
+        running, step, (jnp.int32(0), carry, hist, hist))
+    # steps after the latch re-emit the converged (cost, residual)
+    after = jnp.arange(length) >= n
+    return carry, (jnp.where(after, carry.cost, cs),
+                   jnp.where(after, carry.residual, rs))

@@ -242,14 +242,15 @@ def init_phi(inst: Instance) -> Phi:
 #
 # Three entry points share one device-resident iteration (DESIGN.md §10):
 #
-#   * solve_scan  — the whole loop as ONE jitted lax.scan of static length
-#                   with on-device early-stop masking; composes with
-#                   jax.vmap for batched scenario families (core/batch.py,
-#                   core/scenarios.py).
+#   * solve_scan  — the whole loop as ONE jitted device loop of static
+#                   length with an on-device early-stop latch at which
+#                   it exits; composes with jax.vmap for batched
+#                   scenario families (core/batch.py, core/scenarios.py),
+#                   where the loop runs until every member has latched.
 #   * solve       — the user-facing driver: runs the same scan in chunks and
 #                   checks the early-stop flag on host once per chunk, so a
 #                   run that converges in 50 iterations does not pay for
-#                   max_iters=400 worth of frozen device work.
+#                   max_iters=400 worth of steps.
 #   * solve_loop  — the original per-iteration host-sync python loop, kept
 #                   as the semantic reference (tests/test_batch.py asserts
 #                   scan == loop on every Table II scenario).
@@ -275,9 +276,11 @@ def _scan_chunk(
 ):
     """Jitted single-device wrapper over :func:`engine.scan_chunk`.
 
-    Early-stop is a *mask*, not a break (see the engine docstring): the
-    ``done`` latch freezes the carry and subsequent steps re-emit the
-    converged (cost, residual), keeping history shapes static.  ``accel``
+    Once the ``done`` latch is set the chunk's step loop exits, and the
+    steps it did not run re-emit the converged (cost, residual), keeping
+    history shapes static (see the engine docstring; under ``jax.vmap``, as
+    in :func:`_scan_chunk_batched`, the loop runs while any member is live
+    and latched members keep their carry by a select).  ``accel``
     is a resolved :class:`engine.AccelConfig` (or None) riding as a static
     argument — each distinct config compiles its own program.  ``app_mask``
     ((A,) bool or None) freezes applications (the §16 skip gate).
@@ -308,7 +311,7 @@ def solve_scan(
     app_mask: Optional[jnp.ndarray] = None,
     telemetry=None,
 ) -> GPScan:
-    """Algorithm 1 as a single device-resident ``lax.scan``.
+    """Algorithm 1 as a single device-resident loop (``engine.scan_chunk``).
 
     No host syncs inside the loop; returns dense histories (see
     :class:`GPScan`).  This is the vmap/jit-composable primitive — batched
@@ -396,6 +399,8 @@ def solve(
     never syncs to host — only the ``done`` latch is read back, once every
     ``_SOLVE_CHUNK`` iterations — so converged runs stop early while the
     per-iteration cost stays identical to the fully device-resident scan.
+    The last chunk's step loop exits at the latch
+    (:func:`engine.scan_chunk`), so no step after it runs.
 
     scaled=True enables the quasi-Newton diagonal preconditioner (paper
     Section IV remark on second-order methods).  accel=True (or an

@@ -172,6 +172,51 @@ def test_anderson_accepts_cost_decreasing_mix():
     assert float(out.cost) <= cost_star * (1 + 1e-5)
 
 
+# ------------------------------------------------------ exit at the latch
+
+
+def _bits(tree):
+    return [np.asarray(x).tobytes() for x in jax.tree_util.tree_leaves(tree)]
+
+
+@pytest.mark.parametrize("accel", [None, True], ids=["plain", "accel"])
+def test_frozen_chunk_returns_carry_unchanged(accel):
+    # a carry whose done latch is set passes through a chunk bit for bit
+    # (phi, bookkeeping, Anderson window, telemetry ring) and re-emits its
+    # (cost, residual) at every step
+    inst = network.table_ii_instance("abilene", seed=0, rate_scale=2.0)
+    acc = engine.resolve_accel(accel)
+    tel = engine.resolve_telemetry(True)
+    args = (jnp.float32(0.1), jnp.float32(1e-4), jnp.int32(40),
+            jnp.int32(400), None, None)
+    kw = dict(accel=acc, telemetry=tel)
+    carry = gp._init_carry(inst, gp.init_phi(inst), **kw)
+    carry, _ = gp._scan_chunk(inst, carry, *args, length=4, **kw)
+    assert not bool(carry.done) and int(carry.iters) == 4
+    carry = carry._replace(done=jnp.asarray(True))
+    out, (cs, rs) = gp._scan_chunk(inst, carry, *args, length=3, **kw)
+    assert _bits(out) == _bits(carry)
+    assert np.asarray(cs).tobytes() == np.full(3, carry.cost).tobytes()
+    assert np.asarray(rs).tobytes() == np.full(3, carry.residual).tobytes()
+
+
+@pytest.mark.parametrize("accel", [None, True], ids=["plain", "accel"])
+def test_solve_latched_mid_chunk_matches_scan_without_frozen_tail(accel):
+    # gp.solve latches inside a chunk and runs none of the rest; the scan cut
+    # at that iteration count has no frozen tail: same bits everywhere
+    inst = network.table_ii_instance("abilene", seed=0, rate_scale=2.0)
+    kw = dict(alpha=0.1, accel=accel, telemetry=True)
+    res = gp.solve(inst, max_iters=400, **kw)
+    n = res.iterations
+    assert n % gp._SOLVE_CHUNK != 0 and n < 400
+    scan = gp.solve_scan(inst, max_iters=n, **kw)
+    assert int(scan.iterations) == n
+    for a, b in [(res.phi, scan.phi), (res.telemetry, scan.telemetry),
+                 (res.cost_history, scan.cost_history),
+                 (res.residual_history, scan.residual_history)]:
+        assert _bits(a) == _bits(b)
+
+
 # ------------------------------------------------- batched / sharded parity
 
 

@@ -126,3 +126,81 @@ def test_tagged_pallas_compiles(one_chip):
     text = _compile_text(fn, one_chip, ((B, Vp, W), jnp.uint32),
                          ((B, Vp, W), jnp.uint32))
     assert "tpu_custom_call" in text
+
+
+def _computations(text):
+    """Optimized HLO text -> {computation name: its instruction lines}."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+def _called(line):
+    """Names of the computations an instruction calls."""
+    names = re.findall(r"(?:calls|to_apply|body|condition|true_computation|"
+                       r"false_computation)=%([\w.\-]+)", line)
+    for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+        names += re.findall(r"%([\w.\-]+)", group)
+    return names
+
+
+def _reachable(comps, root):
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            todo += [c for line in comps[name] for c in _called(line)]
+    return seen
+
+
+def _holds_kernel(comps, root):
+    return any('custom_call_target="tpu_custom_call"' in line
+               for name in _reachable(comps, root) for line in comps[name])
+
+
+def test_scan_chunk_loop_exits_at_the_latch(one_chip, monkeypatch):
+    """The chunk's step loop, the one loop whose body runs the GP step's
+    kernels, tests the done latch (a boolean of its state) in its
+    condition: steps after the latch are never run.  A loop of fixed trip
+    count would run the kernels on every frozen step."""
+    from repro.core import engine, gp
+    from repro.kernels import ops
+
+    inst = network.table_ii_instance("abilene", seed=0)
+    acc = engine.resolve_accel(True)
+    carry = gp._init_carry(inst, gp.init_phi(inst), accel=acc)
+    scalars = (jnp.float32(0.1), jnp.float32(1e-4), jnp.int32(40),
+               jnp.int32(400))
+    spec = functools.partial(
+        jax.tree_util.tree_map,
+        lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x),
+                                       sharding=one_chip))
+    # the kernels' dispatch follows the backend at trace time: steer it to
+    # the chip's path, with no trace of the CPU path left in the jit caches
+    jax.clear_caches()
+    monkeypatch.setattr(ops, "INTERPRET", False)
+    monkeypatch.setattr(ops, "_PALLAS_DEFAULT", True)
+    try:
+        text = gp._scan_chunk.lower(
+            spec(inst), spec(carry), *spec(scalars), None, None, length=3,
+            solver="batched_lu", accel=acc).compile().as_text()
+    finally:
+        jax.clear_caches()
+    comps = _computations(text)
+    entry = re.search(r"^ENTRY %([\w.\-]+) ", text, re.MULTILINE).group(1)
+    loops = [re.search(r"condition=%([\w.\-]+), body=%([\w.\-]+)", line)
+             .groups() for name in _reachable(comps, entry)
+             for line in comps[name] if " while(" in line]
+    step_loops = [cond for cond, body in loops if _holds_kernel(comps, body)]
+    assert len(step_loops) == 1
+    assert any(re.search(r"= pred\[\]\S* get-tuple-element\(", line)
+               for line in comps[step_loops[0]])
