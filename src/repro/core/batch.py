@@ -217,7 +217,15 @@ def pad_instances(insts: Sequence[Instance], *,
             )
             for p in padded
         ]
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *padded)
+    return _stack(padded)
+
+
+def _stack(trees):
+    """Stack same-shaped pytrees along a new leading axis, on the host: one
+    transfer per leaf and no device program, whatever the family's size."""
+    return jax.tree_util.tree_map(
+        lambda *xs: jnp.asarray(np.stack([np.asarray(x) for x in xs])),
+        *trees)
 
 
 def batch_size(binst: Instance) -> int:
@@ -261,8 +269,8 @@ def pad_phi(phi: Phi, V: int, A: int, K1: int,
 def pad_phis(phis: Sequence[Phi], insts: Sequence[Instance]) -> Phi:
     """Stack per-instance strategies to match ``pad_instances(insts)``."""
     V, A, K1 = batch_envelope(insts)
-    padded = [pad_phi(p, V, A, K1, inst) for p, inst in zip(phis, insts)]
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *padded)
+    return _stack([pad_phi(p, V, A, K1, inst)
+                   for p, inst in zip(phis, insts)])
 
 
 def unpad_phi(phi: Phi, inst: Instance) -> Phi:

@@ -51,7 +51,9 @@ blocked_sets = engine.blocked_sets
 
 
 class GPScan(NamedTuple):
-    """On-device result of :func:`solve_scan` (a strict superset of GPState).
+    """Result of :func:`solve_scan` (a strict superset of GPState), on the
+    device; :func:`solve_batched` returns the same fields as host (numpy)
+    arrays.
 
     Histories are dense ``(max_iters[+1],)`` arrays: entries past
     ``iterations`` repeat the converged value (the carry is frozen once the
@@ -70,15 +72,26 @@ class GPScan(NamedTuple):
     # ``iterations`` (clamped to R) are zero.  Decode with
     # ``repro.obs.ring_valid(telemetry, iterations)`` (DESIGN.md §19).
     telemetry: Optional[jnp.ndarray] = None
+    # lane-steps the batched driver's vmapped chunk programs executed: per
+    # chunk, the lanes of its bucket times the steps its loop ran
+    # (:func:`solve_batched` only; None elsewhere)
+    lane_steps: Optional[int] = None
+
+
+def _as_array(x):
+    """Host and device arrays as they are; anything else (a list) as a
+    device array."""
+    return x if isinstance(x, (np.ndarray, jax.Array)) else jnp.asarray(x)
 
 
 @dataclasses.dataclass
 class GPResult:
     """Host-side solve summary.
 
-    ``cost_history`` / ``residual_history`` are dense jnp arrays (NOT
-    python lists): ``cost_history[0]`` is the initial cost and entry ``i``
-    is the cost after iteration ``i``.  Results from :func:`solve` are
+    ``cost_history`` / ``residual_history`` are dense arrays (NOT python
+    lists; device arrays from :func:`solve`, host arrays from the family
+    drivers): ``cost_history[0]`` is the initial cost and entry ``i`` is
+    the cost after iteration ``i``.  Results from :func:`solve` are
     already trimmed; un-trimmed dense results (e.g. assembled from
     :func:`solve_scan`) repeat the converged value past ``iterations`` —
     ``trim()`` cuts them back to the committed prefix.
@@ -94,15 +107,15 @@ class GPResult:
     telemetry: Optional[jnp.ndarray] = None
 
     def __post_init__(self):
-        self.cost_history = jnp.asarray(self.cost_history)
-        self.residual_history = jnp.asarray(self.residual_history)
+        self.cost_history = _as_array(self.cost_history)
+        self.residual_history = _as_array(self.residual_history)
 
     def trim(self) -> "GPResult":
         """Cut dense histories back to the committed iteration prefix.
 
         ``cost_history`` shrinks to ``(iterations + 1,)`` (entry 0 is the
         initial cost) and ``residual_history`` to ``(iterations,)``.
-        Idempotent; host-side only (no device work).
+        Idempotent; on host arrays it does no device work.
 
         Example::
 
@@ -375,6 +388,19 @@ def _prev_pow2(n: int) -> int:
     return 1 << (n.bit_length() - 1)
 
 
+def _chunk_lengths(max_iters: int):
+    """The batched driver's chunk schedule, in order: pow2 lengths only
+    (min with the largest pow2 <= the remaining budget), so the whole
+    schedule draws from {8, 16, 32, 64} plus the pow2 ladder of any sub-8
+    tail."""
+    steps, chunk = 0, _CHUNK_MIN
+    while steps < max_iters:
+        length = min(chunk, _prev_pow2(max_iters - steps))
+        yield length
+        steps += length
+        chunk = min(chunk * 2, _CHUNK_MAX)
+
+
 def solve(
     inst: Instance,
     phi0: Optional[Phi] = None,
@@ -471,6 +497,52 @@ def _gather(tree, idx: jnp.ndarray):
     return jax.tree_util.tree_map(lambda x: x[idx], tree)
 
 
+def _host_gather(tree, idx: np.ndarray):
+    """Lanes ``idx`` of every leaf, gathered on the host and put back on
+    the device: no program, whatever the source's size."""
+    return jax.tree_util.tree_map(lambda x: jnp.asarray(np.asarray(x)[idx]),
+                                  tree)
+
+
+@functools.partial(jax.jit, static_argnames=("accel", "telemetry"))
+def _init_family(binst, phi0, n_live, *, accel=None, telemetry=None):
+    """The first carry of a family bucket, as one program: the initial
+    strategies (``init_phi`` where ``phi0`` is None) and their carries;
+    lanes from ``n_live`` on are padding and start latched."""
+    if phi0 is None:
+        phi0 = jax.vmap(init_phi)(binst)
+    carry = jax.vmap(
+        lambda i, p: _init_carry(i, p, accel=accel, telemetry=telemetry)
+    )(binst, phi0)
+    pad = jnp.arange(carry.done.shape[0]) >= n_live
+    return carry._replace(done=carry.done | pad)
+
+
+def _chunk_runner(alpha, tol, patience, max_iters, **static):
+    """``run(inst, carry, allowed_e, allowed_c, length)``: one chunk of the
+    batched driver, with the scalars and static arguments fixed as
+    :func:`solve_batched` passes them."""
+    scalars = (jnp.float32(alpha), jnp.float32(tol), jnp.int32(patience),
+               jnp.int32(max_iters))
+
+    def run(inst, carry, allowed_e, allowed_c, length):
+        return _scan_chunk_batched(inst, carry, *scalars, allowed_e,
+                                   allowed_c, length=length, **static)
+
+    return run
+
+
+@jax.jit
+def _repack(inst, carry, allowed_e, allowed_c, sel, n_live):
+    """Compaction as one program per (source, target) bucket: lanes ``sel``
+    of the instances, carries and masks; lanes from ``n_live`` on are
+    padding (copies of a live lane) and start latched."""
+    inst, carry, allowed_e, allowed_c = _gather(
+        (inst, carry, allowed_e, allowed_c), sel)
+    pad = jnp.arange(sel.shape[0]) >= n_live
+    return inst, carry._replace(done=carry.done | pad), allowed_e, allowed_c
+
+
 def solve_batched(
     binst: Instance,
     phi0: Optional[Phi] = None,
@@ -510,7 +582,23 @@ def solve_batched(
 
     Histories are dense ``(B, max_iters[+1])`` arrays repeating each
     member's converged values past its own stop point; ``iterations``
-    reports each member's stop point.
+    reports each member's stop point.  Every result is a host (numpy)
+    array, copied from the device as lanes retire, so splitting the family
+    into members costs no device program; ``lane_steps`` counts the
+    lane-steps the chunk programs executed (latched and padding lanes
+    included).
+
+    The device work compiles once per bucket: the family's first carry
+    (:func:`_init_family`), each chunk length of the schedule, and each
+    compaction from one bucket to a smaller one (:func:`_repack`); a family
+    whose size is not a power of two is padded to its bucket on the host
+    first.  Under a running ``jax.profiler`` trace the call and its host
+    phases are named spans (``repro.obs.spans.span``):
+    ``gp.solve_batched``, and inside it ``gp.solve_batched.init``, one
+    ``gp.solve_batched.dispatch`` per chunk (the chunk's call through
+    reading its latch, iteration counts and histories back),
+    ``gp.solve_batched.retire`` (copying the finals of retiring lanes) and
+    ``gp.solve_batched.compact``.
 
     Shapes: for a batch of B members padded to (V, A, K1), returns
     ``phi.e (B, A, K1, V, V)``, ``phi.c (B, A, K1, V)``, ``cost``/
@@ -530,123 +618,164 @@ def solve_batched(
         >>> scan.cost.shape, scan.cost_history.shape
         ((4,), (4, 201))
     """
-    B = int(binst.adj.shape[0])
+    with span("gp.solve_batched"):
+        accel = engine.resolve_accel(accel)
+        telemetry = engine.resolve_telemetry(telemetry)
+        B = int(binst.adj.shape[0])
+        # lane -> original member (-1: pad); the lanes of a bucket that is not
+        # a power of two are padded on the host, so every chunk program in this
+        # solve (and any other solve over same-shaped members) hits the same
+        # XLA cache entries as the compaction buckets
+        bucket0 = batch_mod.next_pow2(B) if compact else B
+        ids = np.concatenate([np.arange(B), np.full(bucket0 - B, -1)])
+        inst_p, ae_p, ac_p = binst, allowed_e, allowed_c
+        with span("gp.solve_batched.init"):
+            if bucket0 > B:
+                sel = np.concatenate([np.arange(B),
+                                      np.zeros(bucket0 - B, np.int64)])
+                inst_p, phi0, ae_p, ac_p = _host_gather(
+                    (inst_p, phi0, ae_p, ac_p), sel)
+            carry = _init_family(inst_p, phi0, B, accel=accel,
+                                 telemetry=telemetry)
+            # host-side result buffers, indexed by original member id
+            cost0 = np.asarray(carry.cost)[:B]
+            out_phi_e = np.asarray(carry.phi.e)[:B].copy()
+            out_phi_c = np.asarray(carry.phi.c)[:B].copy()
+        run_chunk = _chunk_runner(alpha, tol, patience, max_iters,
+                                  scaled=scaled, solver=solver,
+                                  blocked=blocked, accel=accel,
+                                  telemetry=telemetry)
+
+        cost_hist = np.zeros((B, max_iters + 1), np.float32)
+        cost_hist[:, 0] = cost0
+        res_hist = np.zeros((B, max_iters), np.float32)
+        out_cost = cost0.copy()
+        out_res = np.full((B,), np.inf, np.float32)
+        out_iters = np.zeros((B,), np.int32)
+        ring = telemetry.ring if telemetry is not None else 0
+        out_tb = np.zeros((B, ring, engine.TEL_WIDTH), np.float32)
+        written = np.zeros((B,), np.int64)     # history filled up to this step
+
+        lane_iters = np.zeros(len(ids), np.int64)   # iterations at chunk start
+        lane_steps = 0
+        steps = 0
+        for length in _chunk_lengths(max_iters):
+            valid = ids >= 0
+            vids = ids[valid]
+            with span("gp.solve_batched.dispatch"):
+                carry, (cs, rs) = run_chunk(inst_p, carry, ae_p, ac_p, length)
+                done = np.asarray(carry.done)
+                iters = np.asarray(carry.iters)
+                cost_hist[vids, steps + 1: steps + 1 + length] = (
+                    np.asarray(cs)[valid])
+                res_hist[vids, steps: steps + length] = np.asarray(rs)[valid]
+            # the loop ran while any lane was live: as many steps as the lane
+            # that committed most, for every lane of the bucket
+            lane_steps += len(ids) * int((iters - lane_iters).max())
+            steps += length
+            written[vids] = steps
+
+            # snapshot finals only for lanes retiring this chunk (done, or the
+            # iteration budget just ran out) — phi is the expensive transfer,
+            # (B, A, K1, V, V), and active lanes would overwrite it anyway
+            retiring = valid & (done | (steps >= max_iters))
+            if retiring.any():
+                with span("gp.solve_batched.retire"):
+                    rids = ids[retiring]
+                    out_phi_e[rids] = np.asarray(carry.phi.e)[retiring]
+                    out_phi_c[rids] = np.asarray(carry.phi.c)[retiring]
+                    out_cost[rids] = np.asarray(carry.cost)[retiring]
+                    out_res[rids] = np.asarray(carry.residual)[retiring]
+                    out_iters[rids] = iters[retiring]
+                    if telemetry is not None:
+                        # rings snapshot at retirement only (same rationale as
+                        # phi: active lanes would overwrite, and compaction
+                        # re-packs lanes — original-id indexing happens here)
+                        out_tb[rids] = np.asarray(carry.tb)[retiring]
+
+            active = valid & ~done
+            n_act = int(active.sum())
+            if n_act == 0:
+                break
+            bucket = batch_mod.next_pow2(n_act)
+            if compact and bucket < len(ids):
+                with span("gp.solve_batched.compact"):
+                    keep = np.flatnonzero(active)
+                    # pad lanes duplicate a live member but start frozen
+                    sel = np.concatenate(
+                        [keep, np.full(bucket - n_act, keep[0], np.int64)])
+                    inst_p, carry, ae_p, ac_p = _repack(
+                        inst_p, carry, ae_p, ac_p, jnp.asarray(sel), n_act)
+                    ids = np.where(np.arange(bucket) < n_act, ids[sel], -1)
+                    iters = iters[sel]
+            lane_iters = iters
+
+        # dense-history contract: repeat converged values past each member's
+        # retirement chunk
+        for m in range(B):
+            w = int(written[m])
+            cost_hist[m, w + 1:] = cost_hist[m, w]
+            if w > 0:
+                res_hist[m, w:] = res_hist[m, w - 1]
+
+        return GPScan(
+            phi=Phi(e=out_phi_e, c=out_phi_c), cost=out_cost, residual=out_res,
+            cost_history=cost_hist, residual_history=res_hist,
+            iterations=out_iters,
+            telemetry=out_tb if telemetry is not None else None,
+            lane_steps=lane_steps,
+        )
+
+
+def warm_batched(
+    binst: Instance,
+    *,
+    alpha: float = 0.02,
+    max_iters: int = 400,
+    tol: float = 1e-4,
+    allowed_e: Optional[jnp.ndarray] = None,
+    allowed_c: Optional[jnp.ndarray] = None,
+    patience: int = 40,
+    scaled: bool = False,
+    compact: bool = True,
+    solver: str = "auto",
+    blocked: str = "bitset",
+    accel=None,
+    telemetry=None,
+) -> None:
+    """Build every program :func:`solve_batched` runs, with the same keyword
+    arguments and with or without ``phi0``, for a family of at most
+    ``binst``'s members of its shapes: each bucket's first carry, its chunk
+    programs at every length of the schedule, and its compactions to every
+    smaller bucket.  Every lane starts latched, so no step is committed.
+
+    A family solved afterwards compiles nothing, whatever its members'
+    iteration counts::
+
+        >>> gp.warm_batched(binst, alpha=0.1, max_iters=256)
+        >>> scan = gp.solve_batched(binst, alpha=0.1, max_iters=256)
+    """
     accel = engine.resolve_accel(accel)
     telemetry = engine.resolve_telemetry(telemetry)
-    if phi0 is None:
-        phi0 = jax.vmap(init_phi)(binst)
-    carry = jax.vmap(
-        lambda i, p: _init_carry(i, p, accel=accel, telemetry=telemetry)
-    )(binst, phi0)
-    alpha_, tol_ = jnp.float32(alpha), jnp.float32(tol)
-    patience_, max_iters_ = jnp.int32(patience), jnp.int32(max_iters)
-
-    # host-side result buffers, indexed by original member id
-    cost_hist = np.zeros((B, max_iters + 1), np.float32)
-    cost_hist[:, 0] = np.asarray(carry.cost)
-    res_hist = np.zeros((B, max_iters), np.float32)
-    out_phi_e = np.asarray(phi0.e).copy()
-    out_phi_c = np.asarray(phi0.c).copy()
-    out_cost = np.asarray(carry.cost).copy()
-    out_res = np.full((B,), np.inf, np.float32)
-    out_iters = np.zeros((B,), np.int32)
-    ring = telemetry.ring if telemetry is not None else 0
-    out_tb = np.zeros((B, ring, engine.TEL_WIDTH), np.float32)
-    written = np.zeros((B,), np.int64)     # history filled up to this step
-
-    ids = np.arange(B)                      # lane -> original member (-1: pad)
-    inst_p, ae_p, ac_p = binst, allowed_e, allowed_c
-    # align the initial batch to a power-of-two bucket so every chunk
-    # program in this solve (and any other solve over same-shaped members)
-    # hits the same XLA cache entries as the compaction buckets
-    bucket0 = batch_mod.next_pow2(B)
-    if compact and bucket0 > B:
-        sel = np.concatenate([np.arange(B), np.zeros(bucket0 - B, np.int64)])
-        sel_j = jnp.asarray(sel)
-        inst_p = _gather(inst_p, sel_j)
-        carry = _gather(carry, sel_j)
-        if ae_p is not None:
-            ae_p = ae_p[sel_j]
-        if ac_p is not None:
-            ac_p = ac_p[sel_j]
-        pad0 = jnp.arange(bucket0) >= B
-        carry = carry._replace(done=carry.done | pad0)
-        ids = np.concatenate([ids, np.full(bucket0 - B, -1)])
-    steps = 0
-    chunk = _CHUNK_MIN
-    while steps < max_iters:
-        # pow2 lengths only (min with the largest pow2 <= the remaining
-        # budget), so the whole schedule draws from {8, 16, 32, 64} plus
-        # the pow2 ladder of any sub-8 tail
-        length = min(chunk, _prev_pow2(max_iters - steps))
-        chunk = min(chunk * 2, _CHUNK_MAX)
-        carry, (cs, rs) = _scan_chunk_batched(
-            inst_p, carry, alpha_, tol_, patience_, max_iters_, ae_p, ac_p,
-            length=length, scaled=scaled, solver=solver, blocked=blocked,
-            accel=accel, telemetry=telemetry,
-        )
-        valid = ids >= 0
-        vids = ids[valid]
-        cost_hist[vids, steps + 1: steps + 1 + length] = np.asarray(cs)[valid]
-        res_hist[vids, steps: steps + length] = np.asarray(rs)[valid]
-        steps += length
-        written[vids] = steps
-
-        done = np.asarray(carry.done)
-        # snapshot finals only for lanes retiring this chunk (done, or the
-        # iteration budget just ran out) — phi is the expensive transfer,
-        # (B, A, K1, V, V), and active lanes would overwrite it anyway
-        retiring = valid & (done | (steps >= max_iters))
-        if retiring.any():
-            rids = ids[retiring]
-            out_phi_e[rids] = np.asarray(carry.phi.e)[retiring]
-            out_phi_c[rids] = np.asarray(carry.phi.c)[retiring]
-            out_cost[rids] = np.asarray(carry.cost)[retiring]
-            out_res[rids] = np.asarray(carry.residual)[retiring]
-            out_iters[rids] = np.asarray(carry.iters)[retiring]
-            if telemetry is not None:
-                # rings snapshot at retirement only (same rationale as phi:
-                # active lanes would overwrite, and compaction re-packs
-                # lanes — original-id indexing happens here, once)
-                out_tb[rids] = np.asarray(carry.tb)[retiring]
-
-        active = valid & ~done
-        n_act = int(active.sum())
-        if n_act == 0:
-            break
-        bucket = batch_mod.next_pow2(n_act)
-        if compact and bucket < len(ids):
-            keep = np.flatnonzero(active)
-            sel = np.concatenate(
-                [keep, np.full(bucket - n_act, keep[0], np.int64)])
-            sel_j = jnp.asarray(sel)
-            inst_p = _gather(inst_p, sel_j)
-            carry = _gather(carry, sel_j)
-            if ae_p is not None:
-                ae_p = ae_p[sel_j]
-            if ac_p is not None:
-                ac_p = ac_p[sel_j]
-            # pad lanes duplicate a live member but start frozen
-            pad = jnp.arange(bucket) >= n_act
-            carry = carry._replace(done=carry.done | pad)
-            ids = np.where(np.arange(bucket) < n_act, ids[sel], -1)
-
-    # dense-history contract: repeat converged values past each member's
-    # retirement chunk
-    for m in range(B):
-        w = int(written[m])
-        cost_hist[m, w + 1:] = cost_hist[m, w]
-        if w > 0:
-            res_hist[m, w:] = res_hist[m, w - 1]
-
-    return GPScan(
-        phi=Phi(e=jnp.asarray(out_phi_e), c=jnp.asarray(out_phi_c)),
-        cost=jnp.asarray(out_cost), residual=jnp.asarray(out_res),
-        cost_history=jnp.asarray(cost_hist),
-        residual_history=jnp.asarray(res_hist),
-        iterations=jnp.asarray(out_iters),
-        telemetry=jnp.asarray(out_tb) if telemetry is not None else None,
-    )
+    B = int(binst.adj.shape[0])
+    top = batch_mod.next_pow2(B) if compact else B
+    buckets = [top >> i for i in range(top.bit_length())] if compact else [B]
+    run_chunk = _chunk_runner(alpha, tol, patience, max_iters, scaled=scaled,
+                              solver=solver, blocked=blocked, accel=accel,
+                              telemetry=telemetry)
+    lengths = sorted(set(_chunk_lengths(max_iters)))
+    family = _host_gather((binst, allowed_e, allowed_c),
+                          np.arange(top) % B)
+    for n, b in enumerate(buckets):
+        inst_b, ae_b, ac_b = _host_gather(family, np.arange(b))
+        carry = _init_family(inst_b, None, 0, accel=accel,
+                             telemetry=telemetry)
+        carry = _init_family(inst_b, _host_gather(carry.phi, np.arange(b)),
+                             0, accel=accel, telemetry=telemetry)
+        for length in lengths:
+            run_chunk(inst_b, carry, ae_b, ac_b, length)
+        for to in buckets[n + 1:]:
+            _repack(inst_b, carry, ae_b, ac_b, jnp.asarray(np.arange(to)), to)
 
 
 def solve_loop(

@@ -34,6 +34,7 @@ import time
 from typing import Callable, Optional, Sequence
 
 import jax
+import numpy as np
 
 from repro.core import batch, gp, network
 from repro.core.traffic import Phi
@@ -97,8 +98,6 @@ def _fig6_congestion(**kw) -> list[Scenario]:
 
 
 def _fig7_packetsize(**kw) -> list[Scenario]:
-    import numpy as np
-
     seed = kw.get("seed", 0)
     l0s = kw.get("l0_values", FIG7_L0)
     out = []
@@ -220,6 +219,9 @@ class SweepResult:
     results: list[gp.GPResult]      # aligned with scenarios, phi un-padded
     seconds: float                  # wall clock for the solve(s)
     n_batches: int                  # #kind-groups the family was split into
+    # lane-steps the batched driver executed over every group
+    # (``gp.GPScan.lane_steps``); None where a group ran elsewhere (mesh=)
+    lane_steps: Optional[int] = None
 
     def by_label(self) -> dict[str, gp.GPResult]:
         return {s.label: r for s, r in zip(self.scenarios, self.results)}
@@ -233,7 +235,7 @@ def solve_family(
     mesh=None,
     mesh_axis: str = "stage",
     **gp_kwargs,
-) -> list[gp.GPResult]:
+) -> tuple[list[gp.GPResult], Optional[int]]:
     """Solve same-cost-family instances as ONE padded, vmapped batch.
 
     ``masks_fn`` (e.g. ``baselines.spoc_masks``) maps an Instance to
@@ -249,7 +251,10 @@ def solve_family(
     spread across devices while solving the identical problems.
 
     Returns per-instance trimmed GPResults with padding stripped from phi
-    and histories taken from the batched dense scan outputs.
+    and histories taken from the batched dense scan outputs, all host
+    (numpy) arrays, split from one host copy of the family; and the
+    lane-steps the batched driver executed (``gp.GPScan.lane_steps``, None
+    on the mesh path).
     """
     binst = batch.pad_instances(insts)
     phi0 = batch.pad_phis(phi0s, insts) if phi0s is not None else None
@@ -266,16 +271,15 @@ def solve_family(
             binst, mesh, axis=mesh_axis, phi0=phi0, **gp_kwargs)
     else:
         scan = gp.solve_batched(binst, phi0, **gp_kwargs)
-    out = []
-    for b, inst in enumerate(insts):
-        member = jax.tree_util.tree_map(lambda x: x[b], scan)
-        out.append(gp.GPResult(
-            phi=batch.unpad_phi(member.phi, inst),
-            cost_history=member.cost_history,
-            residual_history=member.residual_history,
-            iterations=int(member.iterations),
-        ).trim())
-    return out
+    phi_e, phi_c, costs, resids, iters = (np.asarray(x) for x in (
+        scan.phi.e, scan.phi.c, scan.cost_history, scan.residual_history,
+        scan.iterations))
+    out = [gp.GPResult(
+        phi=batch.unpad_phi(Phi(e=phi_e[b], c=phi_c[b]), inst),
+        cost_history=costs[b], residual_history=resids[b],
+        iterations=int(iters[b]),
+    ).trim() for b, inst in enumerate(insts)]
+    return out, scan.lane_steps
 
 
 def run_sweep(name_or_scenarios, *, sweep_kwargs: Optional[dict] = None,
@@ -328,16 +332,19 @@ def run_sweep(name_or_scenarios, *, sweep_kwargs: Optional[dict] = None,
         groups.setdefault(key, []).append(idx)
 
     results: list[Optional[gp.GPResult]] = [None] * len(scenarios)
+    lane_steps = 0
     t0 = time.perf_counter()
     for idxs in groups.values():
-        group_res = solve_family([scenarios[i].instance for i in idxs],
-                                 masks_fn=masks_fn, mesh=mesh,
-                                 mesh_axis=mesh_axis, **gp_kwargs)
+        group_res, lanes = solve_family(
+            [scenarios[i].instance for i in idxs], masks_fn=masks_fn,
+            mesh=mesh, mesh_axis=mesh_axis, **gp_kwargs)
         for i, r in zip(idxs, group_res):
             results[i] = r
+        lane_steps = None if lanes is None or lane_steps is None else (
+            lane_steps + lanes)
     seconds = time.perf_counter() - t0
     return SweepResult(scenarios=scenarios, results=results, seconds=seconds,
-                       n_batches=len(groups))
+                       n_batches=len(groups), lane_steps=lane_steps)
 
 
 def run_sweep_serial(name_or_scenarios, *, sweep_kwargs: Optional[dict] = None,
@@ -396,8 +403,6 @@ def run_sweep_chained(name_or_scenarios, *,
     carry, so the Anderson history and adaptive stepsize never leak across
     chain members (only the warm-started phi does).
     """
-    import numpy as np
-
     if isinstance(name_or_scenarios, str):
         scenarios = expand(name_or_scenarios, **(sweep_kwargs or {}))
     else:

@@ -24,8 +24,10 @@ and this Chrome trace where one was exported beside it.
 Every span also lands in a running ``jax.profiler`` trace, on the same
 clock as the device's operations (:func:`span`); with no profiler running
 that records nothing.  The solver's drivers use :func:`span` directly
-(``gp.solve`` and its phases), so a profiled run can attribute each gap in
-the device's work to the host phase that left it.
+(``gp.solve`` and its phases ``.init``, ``.dispatch``, ``.trim``;
+``gp.solve_batched`` and its phases ``.init``, ``.dispatch``, ``.retire``,
+``.compact``), so a profiled run can attribute each gap in the device's
+work to the host phase that left it.
 """
 
 from __future__ import annotations
