@@ -31,6 +31,14 @@ strictly-lower panel from the log-depth Neumann product (MXU matmuls); the
 trailing submatrix update ``A22 -= L21 @ U12`` is a single MXU matmul —
 the O(V^3) bulk of the factorization.  Both are written through the
 output ref.
+
+Sequential trip counts: the column loop of :func:`lu_factor` and the row
+sweeps of :func:`lu_solve` / :func:`chain_solve` run to the logical V of
+their input, not to the padded tile width Vp.  The tile, the panels and the
+lane masks keep their padded, aligned shapes; only the steps on the pad are
+skipped.  The pad is an identity block with a zero right-hand side, so those
+steps are no-ops on every real entry (see :func:`_lu_kernel` and
+:func:`_two_sweep`), and V = Vp runs every step.
 """
 
 from __future__ import annotations
@@ -296,12 +304,19 @@ def _pad_dim(V: int, interpret: bool) -> int:
     return -(-V // mult) * mult
 
 
-def _lu_kernel(a_ref, lu_ref, *, nb: int):
-    """Unpivoted blocked LU of one (Vp, Vp) matrix.
+def _lu_kernel(a_ref, lu_ref, *, nb: int, V: int):
+    """Unpivoted blocked LU of one (Vp, Vp) matrix whose logical size is V.
 
     Column panels of width ``nb`` start at multiples of ``nb`` (= the lane
     width on TPU), so every static slice below is tile-aligned; the panel's
     trailing block row and submatrix are written through the output ref.
+
+    Columns k >= V are not eliminated: such a step would write only rows > k
+    of columns >= k (its multipliers in column k, its update masked to
+    ``cid > k``), so it never touches the real block ``[:V, :V]``, whatever
+    its values.  A panel that starts at or past V (only with ``nb`` below
+    the lane width) runs no column step, and its trailing update writes
+    only the pad.
     """
     a = a_ref[0].astype(jnp.float32)
     Vp = a.shape[0]
@@ -327,7 +342,7 @@ def _lu_kernel(a_ref, lu_ref, *, nb: int):
             # store the multipliers below the diagonal of column k
             return jnp.where((col == k) & (row > k), l, a)
 
-        a = jax.lax.fori_loop(p0, p1, col_step, a)
+        a = jax.lax.fori_loop(p0, min(p1, V), col_step, a)
 
         if p1 < Vp:
             L11 = a[p0:p1, p0:p1]
@@ -362,7 +377,7 @@ def _nilpotent_panel_inv(X: jnp.ndarray) -> jnp.ndarray:
     return acc
 
 
-def _two_sweep(row, b: jnp.ndarray, *, trans: int) -> jnp.ndarray:
+def _two_sweep(row, b: jnp.ndarray, *, trans: int, V: int) -> jnp.ndarray:
     """In-kernel two-sweep substitution on a packed L\\U factor.
 
     ``row(i)`` reads row i of the packed factor, (1, Vp), from its ref;
@@ -371,6 +386,10 @@ def _two_sweep(row, b: jnp.ndarray, *, trans: int) -> jnp.ndarray:
     (row i of L, then of U), trans=1 the column-oriented (axpy) form, whose
     column i of U^T / L^T is row i of U / L — so no transposed copy of the
     factor is needed.
+
+    Both sweeps visit rows 0..V-1 only.  Past V the factor is the identity
+    and ``b`` is +0, so a pad step would leave every real lane as it is and
+    write +0 to its own pad lane, which already holds +0.
     """
     Vp = b.shape[-1]
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, Vp), 1)
@@ -385,7 +404,7 @@ def _two_sweep(row, b: jnp.ndarray, *, trans: int) -> jnp.ndarray:
             return jnp.where(lane == i, y - s, y)
 
         def bwd(j, x):        # upper U with diagonal, dot form
-            i = Vp - 1 - j
+            i = V - 1 - j
             r = row(i)
             s = jnp.sum(jnp.where(lane > i, r, 0.0) * x, axis=1,
                         keepdims=True)
@@ -398,26 +417,26 @@ def _two_sweep(row, b: jnp.ndarray, *, trans: int) -> jnp.ndarray:
                              jnp.where(lane > i, v - vi * r, v))
 
         def bwd(j, v):        # L^T (unit upper), axpy form
-            i = Vp - 1 - j
+            i = V - 1 - j
             vi = pick(v, i)
             return jnp.where(lane < i, v - vi * row(i), v)
 
-    y = jax.lax.fori_loop(0, Vp, fwd, b)
-    return jax.lax.fori_loop(0, Vp, bwd, y)
+    y = jax.lax.fori_loop(0, V, fwd, b)
+    return jax.lax.fori_loop(0, V, bwd, y)
 
 
-def _solve_kernel(lu_ref, b_ref, x_ref, *, trans: int):
+def _solve_kernel(lu_ref, b_ref, x_ref, *, trans: int, V: int):
     """Two-sweep substitution for one packed-LU system (trans=0: L U x = b,
     trans=1: (L U)^T x = b)."""
     def row(i):
         return lu_ref[0, pl.ds(i, 1), :].astype(jnp.float32)
 
     b = b_ref[0].astype(jnp.float32)                             # (1, Vp)
-    x_ref[0] = _two_sweep(row, b, trans=trans).astype(x_ref.dtype)
+    x_ref[0] = _two_sweep(row, b, trans=trans, V=V).astype(x_ref.dtype)
 
 
 def _chain_solve_kernel(lu_ref, base_ref, mult_ref, x_ref, *, trans: int,
-                        reverse: bool, clamp: bool, K: int):
+                        reverse: bool, clamp: bool, K: int, V: int):
     """Fused chain of substitutions over one (K, Vp, Vp) factor stack.
 
     One batch member (= one app's whole stage chain) per grid step; the
@@ -429,6 +448,10 @@ def _chain_solve_kernel(lu_ref, base_ref, mult_ref, x_ref, *, trans: int,
     Assumes identity row permutation (the unpivoted Pallas factors of
     :func:`lu_factor`); LAPACK-pivoted reference factors must go through
     :func:`ref_chain_solve` instead (kernels/ops.py dispatches).
+
+    Each stage's sweeps stop at the logical V (:func:`_two_sweep`): base and
+    mult are +0 on the pad, so the carry's pad lanes stay +0 from stage to
+    stage, as each skipped pad step would leave them.
     """
     Vp = lu_ref.shape[-1]
 
@@ -440,7 +463,7 @@ def _chain_solve_kernel(lu_ref, base_ref, mult_ref, x_ref, *, trans: int,
 
         base_k = base_ref[0, pl.ds(k, 1), :].astype(jnp.float32)
         mult_k = mult_ref[0, pl.ds(k, 1), :].astype(jnp.float32)
-        x = _two_sweep(row, base_k + mult_k * carry, trans=trans)
+        x = _two_sweep(row, base_k + mult_k * carry, trans=trans, V=V)
         if clamp:
             x = jnp.maximum(x, 0.0)
         x_ref[0, pl.ds(k, 1), :] = x.astype(x_ref.dtype)
@@ -458,14 +481,15 @@ def lu_factor(mats: jnp.ndarray, *, nb: int = DEFAULT_NB,
     """Unpivoted blocked LU of a (B, V, V) batch -> packed (B, V, V) factors.
 
     The pad region is an identity block, whose LU is itself, so padding and
-    slicing commute with the factorization.
+    slicing commute with the factorization, and the kernel eliminates only
+    the first V columns.
     """
     B, V, _ = mats.shape
     Vp = _pad_dim(V, interpret)
     a = _pad_square(mats.astype(jnp.float32), Vp)
 
     out = pl.pallas_call(
-        functools.partial(_lu_kernel, nb=min(nb, Vp)),
+        functools.partial(_lu_kernel, nb=min(nb, Vp), V=V),
         grid=(B,),
         in_specs=[pl.BlockSpec((1, Vp, Vp), lambda b: (b, 0, 0))],
         out_specs=pl.BlockSpec((1, Vp, Vp), lambda b: (b, 0, 0)),
@@ -485,7 +509,7 @@ def lu_solve(lu: jnp.ndarray, rhs: jnp.ndarray, *, trans: int = 0,
     b = jnp.pad(rhs.astype(jnp.float32), ((0, 0), (0, Vp - V)))
 
     out = pl.pallas_call(
-        functools.partial(_solve_kernel, trans=trans),
+        functools.partial(_solve_kernel, trans=trans, V=V),
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, Vp, Vp), lambda i: (i, 0, 0)),
@@ -517,7 +541,7 @@ def chain_solve(lu: jnp.ndarray, base: jnp.ndarray, mult: jnp.ndarray,
 
     out = pl.pallas_call(
         functools.partial(_chain_solve_kernel, trans=trans, reverse=reverse,
-                          clamp=clamp, K=K),
+                          clamp=clamp, K=K, V=V),
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, K, Vp, Vp), lambda b: (b, 0, 0, 0)),

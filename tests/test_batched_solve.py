@@ -6,8 +6,12 @@ Covers the PR's kernel deliverables:
     for both the reference (LAPACK) path and the Pallas interpret path;
   * a singular / near-singular member raises the per-member flag without
     poisoning the rest of the batch;
+  * loops that stop at the logical V give the bits of the full padded
+    trip count;
   * end-to-end ``gp.solve`` cost parity vs the seed per-stage solver.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -242,3 +246,64 @@ def test_pallas_blocked_path_crosses_panel_boundary():
     np.testing.assert_allclose(np.asarray(x), np.asarray(_oracle(mats, rhs)),
                                atol=1e-5, rtol=1e-5)
     assert np.asarray(bs.factor_ok(lu)).all()
+
+
+def _bits(x):
+    return np.asarray(x, np.float32).view(np.uint32)
+
+
+def _same_answers(got, want):
+    """Bit-identical where finite; non-finite in the same places."""
+    got, want = np.asarray(got), np.asarray(want)
+    fin = np.isfinite(want)
+    np.testing.assert_array_equal(np.isfinite(got), fin)
+    np.testing.assert_array_equal(_bits(got[fin]), _bits(want[fin]))
+
+
+def _pad_vec(x, Vp):
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, Vp - x.shape[-1])])
+
+
+@pytest.mark.parametrize("op,V,nb", [
+    *[(op, V, bs.DEFAULT_NB) for op in ("factor", "solve0", "solve1",
+                                         "traffic", "marginals", "singular")
+      for V in (11, 33, 100)],
+    ("factor", 2 * bs.DEFAULT_NB + 5, bs.DEFAULT_NB),  # three panels
+    ("factor", 33, 8),    # the last panel holds V: its loop stops inside it
+    ("factor", 11, 4),    # a panel past V runs no column step at all
+])
+def test_loops_at_logical_V_match_full_trip_count(op, V, nb):
+    """The kernels' sequential loops stop at the logical V.  Feeding the
+    same systems pre-padded with the kernels' own identity tail to their Vp
+    (so V = Vp and every pad step runs, as a full-length loop would) gives
+    the same bits on [:V]."""
+    Vp = bs._pad_dim(V, interpret=True)
+    B, K = 3, 3
+    mats, rhs = _mk_systems(jax.random.PRNGKey(V * 10 + nb), B * K, V)
+    if op == "singular":
+        bad = mats[1].at[:, 5].set(0.0).at[5, :].set(0.0)
+        mats = mats.at[1].set(bad)
+    f = functools.partial(bs.lu_factor, nb=nb, interpret=True)
+    lu = f(mats)
+    if op in ("factor", "singular"):
+        full = f(bs._pad_square(mats, Vp))[:, :V, :V]
+        _same_answers(lu, full)
+        np.testing.assert_array_equal(np.asarray(bs.factor_ok(lu)),
+                                      np.asarray(bs.factor_ok(full)))
+    if op == "singular":
+        assert not np.asarray(bs.factor_ok(lu))[1]
+    if op in ("solve0", "solve1", "singular"):
+        for trans in ((0, 1) if op == "singular" else (int(op[-1]),)):
+            s = functools.partial(bs.lu_solve, trans=trans, interpret=True)
+            _same_answers(s(lu, rhs),
+                          s(bs._pad_square(lu, Vp), _pad_vec(rhs, Vp))[:, :V])
+    if op in ("traffic", "marginals"):
+        trans, reverse, clamp = ((1, False, False) if op == "traffic"
+                                 else (0, True, True))
+        base = rhs.reshape(B, K, V) - (0.5 if clamp else 0.0)
+        mult = jnp.flip(rhs, 0).reshape(B, K, V)
+        c = functools.partial(bs.chain_solve, trans=trans, reverse=reverse,
+                              clamp=clamp, interpret=True)
+        _same_answers(c(lu.reshape(B, K, V, V), base, mult),
+                      c(bs._pad_square(lu, Vp).reshape(B, K, Vp, Vp),
+                        _pad_vec(base, Vp), _pad_vec(mult, Vp))[..., :V])

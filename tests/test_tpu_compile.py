@@ -67,8 +67,9 @@ def _kernel_named(text, name):
 F32 = jnp.float32
 
 
-@pytest.mark.parametrize("V,B", [(100, 30 * 3), (300, 3 * 3)],
-                         ids=["table2-Vp128", "metro-dense-Vp384"])
+@pytest.mark.parametrize("V,B", [(100, 30 * 3), (300, 3 * 3), (11, 32 * 3 * 3)],
+                         ids=["table2-Vp128", "metro-dense-Vp384",
+                              "abilene-Vp128"])
 def test_lu_factor_compiles(one_chip, V, B):
     fn = jax.vmap(functools.partial(bs.lu_factor, interpret=False))
     text = _compile_text(fn, one_chip, ((LADDER, B, V, V), F32))
@@ -85,8 +86,8 @@ def test_lu_solve_compiles(one_chip, trans):
 @pytest.mark.parametrize("trans,reverse,clamp",
                          [(1, False, False), (0, True, True)],
                          ids=["traffic", "marginals"])
-@pytest.mark.parametrize("V,A", [(100, 30), (300, 3)],
-                         ids=["Vp128", "Vp384"])
+@pytest.mark.parametrize("V,A", [(100, 30), (300, 3), (11, 32 * 3)],
+                         ids=["Vp128", "Vp384", "abilene-Vp128"])
 def test_chain_solve_compiles(one_chip, V, A, trans, reverse, clamp):
     K = 3
     fn = jax.vmap(functools.partial(bs.chain_solve, trans=trans,
